@@ -656,8 +656,8 @@ def main(argv=None, print_csv: bool = True) -> List[str]:
                         help="steady-state job count of the fused scale replay")
     parser.add_argument("--fused-shards", type=int, default=1,
                         help="devices to shard_map the pair fan-out over "
-                        "(clamped to the visible device count; force host "
-                        "devices via XLA_FLAGS to exceed 1 on CPU)")
+                        "(more than the visible device count is an error; "
+                        "force host devices via XLA_FLAGS to exceed 1 on CPU)")
     parser.add_argument("--fused-check-nodes", type=int, default=8,
                         help="nodes of the fused-vs-host bit-parity churn gate")
     parser.add_argument("--fused-check-rounds", type=int, default=10,
@@ -809,4 +809,7 @@ def main(argv=None, print_csv: bool = True) -> List[str]:
 if __name__ == "__main__":
     import sys
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main(sys.argv[1:])

@@ -12,7 +12,7 @@ Prints ``name,us_per_call,derived`` CSV rows.  Mapping to the paper:
   profiling_cost     Fig. 18              estimator quality
   sim_fidelity       Table 2              simulator variance
   matching_microbench (beyond paper)      LAP solver comparison
-  kernels_microbench  (substrate)         Pallas kernels (interpret)
+  kernels_microbench  (substrate)         Pallas kernels (interpret off-TPU)
   roofline_report     (substrate)         dry-run roofline table
   perf_summary        (substrate)         baseline vs optimized dominant terms
 
@@ -62,4 +62,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

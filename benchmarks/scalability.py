@@ -187,4 +187,7 @@ def main(argv=None, print_csv: bool = True) -> List[str]:
 if __name__ == "__main__":
     import sys
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main(sys.argv[1:])

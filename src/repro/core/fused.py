@@ -33,8 +33,9 @@ round:
   via ``--xla_force_host_platform_device_count`` (tests force 8).
 * **auction via lax.while_loop** — both the pair fan-out and the node
   match reuse the Jacobi bid round of :mod:`repro.core.matching.auction`;
-  warm rounds run a single phase at ``eps_min`` (valid for any initial
-  prices on square instances), cold rounds the full epsilon schedule.
+  dirty pairs and the node match run the full epsilon schedule seeded
+  with the cached prices (valid for any initial prices on square
+  instances), shifted to a zero minimum so f32 keeps resolving ``eps``.
 
 Exactness / parity contract: scaled costs are integers and the tie-break
 scale a power of two, so while ``k_l * scale / tb_scale < 2^24`` every
@@ -55,7 +56,6 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.cluster import EMPTY, PlacementPlan, count_migrations
@@ -201,12 +201,19 @@ def _fused_round(
     # --- in-program cost assembly (exact integers in f32) ---------------- #
     cost_p = _pair_costs(pi_slots, pj_slots, weights_scaled).reshape(n_pairs, kl, kl)
 
-    # clean pairs re-enter at their cached optimum (zero bid rounds);
-    # dirty pairs warm-start from cached prices when the cache is live
+    # clean pairs re-enter at their cached optimum (zero bid rounds).  Dirty
+    # pairs, like the node match below, run the full epsilon schedule from
+    # the cached prices: one phase at eps_min from stale prices can take
+    # ~span/eps bid rounds, and ran past max_iters on saturated Simulator
+    # rounds from 16 nodes up.  Cached prices are shifted to a zero minimum
+    # (a uniform shift changes no bid), so they cannot climb round over
+    # round until f32 no longer resolves eps.
     arange_kl = jnp.arange(kl, dtype=jnp.int32)
     init_col = jnp.where(dirty[:, None], -1, cache_col_of)
-    init_prices = jnp.where(cache_valid, cache_prices, jnp.zeros_like(cache_prices))
-    warm = ~dirty | cache_valid  # clean: eps_min re-entry; dirty+cache: warm lane
+    init_prices = jnp.where(
+        cache_valid, cache_prices - cache_prices.min(axis=1, keepdims=True), 0.0
+    )
+    warm = ~dirty
 
     # --- sharded pair fan-out -------------------------------------------- #
     pad = (-n_pairs) % shards
@@ -229,12 +236,12 @@ def _fused_round(
 
     if shards > 1:
         mesh = Mesh(np.array(jax.devices()[:shards]), ("pairs",))
-        solve_shard = shard_map(
+        solve_shard = jax.shard_map(
             solve_shard,
             mesh=mesh,
             in_specs=(P("pairs"), P("pairs"), P("pairs"), P("pairs")),
             out_specs=(P("pairs"), P("pairs"), P("pairs"), P("pairs")),
-            check_rep=False,
+            check_vma=False,
         )
     col_of, prices, iters, conv = solve_shard(cost_p, init_col, init_prices, warm)
     if pad:
@@ -253,9 +260,9 @@ def _fused_round(
     node_col, node_prices, node_iters, node_conv = _pair_auction(
         node_cost,
         eps_node,
-        jnp.where(cache_valid, cache_node_prices, jnp.zeros_like(cache_node_prices)),
+        jnp.where(cache_valid, cache_node_prices - cache_node_prices.min(), 0.0),
         jnp.full((kc,), -1, jnp.int32),
-        cache_valid,
+        False,
         max_iters,
         False,  # node instance: plain jnp assembly (one LAP, no fan-out win)
         tb_node,
@@ -291,6 +298,53 @@ def _fused_round(
     )
 
 
+def lower_fused_round(
+    kc: int,
+    kl: int,
+    pmax: int,
+    n_weights: int,
+    *,
+    shards: int = 1,
+    use_kernel: bool = False,
+    tie_break: bool = False,
+    max_iters: int = 20_000,
+    sharding=None,
+):
+    """Lower :func:`_fused_round` for one cluster shape without running it.
+
+    The arguments are shapes only (``n_weights`` is the weight table's
+    length, ``max_id + 2``), so ``.compile()`` on the result gives the
+    program a :class:`FusedMigrationPlanner` of that shape would run, and
+    its ``as_text()`` / ``memory_analysis()``.  ``sharding`` places every
+    argument, e.g. on a described device of a chip that is not attached.
+    """
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    slots = arg((kc, kl, pmax), jnp.int32)
+    return _fused_round.lower(
+        slots,
+        slots,
+        slots,
+        arg((n_weights,), jnp.float32),
+        arg((kc, kc), jnp.float32),
+        slots,
+        slots,
+        arg((kc * kc, kl), jnp.int32),
+        arg((kc * kc, kl), jnp.float32),
+        arg((kc,), jnp.float32),
+        arg((), jnp.bool_),
+        kc=kc,
+        kl=kl,
+        shards=shards,
+        max_iters=max_iters,
+        use_kernel=use_kernel,
+        tb_pair=_tb_scale(kl, kl) if tie_break else 0.0,
+        tb_node=_tb_scale(kc, kc) if tie_break else 0.0,
+    )
+
+
 class FusedMigrationPlanner:
     """Device-resident Algorithm-2 planner: one jitted, sharded program and
     one readout per round (see module docstring).
@@ -311,7 +365,13 @@ class FusedMigrationPlanner:
         max_iters: int = 20_000,
         obs=None,
     ):
-        self.shards = max(1, min(int(shards), len(jax.devices())))
+        n_dev = len(jax.devices())
+        if not 1 <= int(shards) <= n_dev:
+            raise ValueError(
+                f"shards={shards}: the pair fan-out needs that many devices "
+                f"and {n_dev} are visible"
+            )
+        self.shards = int(shards)
         self.use_kernel = bool(use_kernel)
         self.max_iters = int(max_iters)
         #: opt-in observability bundle — spans around the fused program,

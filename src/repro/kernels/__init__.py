@@ -1,9 +1,9 @@
 """Pallas TPU kernels for Tesserae's compute hot spots.
 
 Three kernels, each with a ``ref.py`` pure-jnp oracle and a jit'd wrapper in
-``ops.py``; all are validated in ``interpret=True`` mode on CPU (this
-container) and written with explicit BlockSpec VMEM tiling for TPU v5e as
-the target:
+``ops.py``; all run compiled on TPU and in interpret mode on the CPU
+(where the tests validate them), written with explicit BlockSpec VMEM
+tiling for TPU v5e as the target:
 
 * ``lap_bid``        — the auction-algorithm bid step (masked row top-2 over
                        the benefit-minus-price matrix).  This is the inner

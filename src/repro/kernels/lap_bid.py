@@ -63,10 +63,20 @@ def _block_dims(n: int, m: int) -> tuple[int, int]:
 
 
 def _tile_top2(vals, col_offset):
-    """(best, arg, second) of one (BR, BC) tile, args in global columns."""
+    """(best, arg, second) of one (BR, BC) tile, args in global columns.
+
+    ``arg`` is the FIRST column holding the row maximum — ``jnp.argmax``'s
+    rule, spelled out because the TPU lowering of ``argmax`` inside a
+    kernel does not promise which of several equal maxima it returns (on
+    a v5e the fused migrate program's plans then differed from the jnp
+    bid path's on tied costs)."""
     col_ids = tile_col_ids(vals.shape, col_offset)
     tile_best = jnp.max(vals, axis=1, keepdims=True)  # (BR, 1)
-    tile_arg = (jnp.argmax(vals, axis=1) + col_offset).astype(jnp.int32)[:, None]
+    tile_arg = jnp.min(
+        jnp.where(vals == tile_best, col_ids, jnp.iinfo(jnp.int32).max),
+        axis=1,
+        keepdims=True,
+    )
     masked = jnp.where(col_ids == tile_arg, NEG_INF, vals)
     tile_second = jnp.max(masked, axis=1, keepdims=True)
     return tile_best, tile_arg, tile_second

@@ -1,7 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this CPU container) and False on
-TPU; every wrapper has identical semantics to its ``ref.py`` oracle.
+Each wrapper runs its kernel compiled on TPU and in interpret mode
+elsewhere (:func:`default_interpret`; the tests run on the CPU), with
+semantics identical to its ``ref.py`` oracle.
 """
 
 from __future__ import annotations
@@ -22,7 +23,8 @@ from repro.kernels.lap_bid import (
 from repro.kernels.migration_cost import migration_cost_pallas
 
 
-def _default_interpret() -> bool:
+def default_interpret() -> bool:
+    """True (interpret mode) unless the default backend is a TPU."""
     return jax.default_backend() != "tpu"
 
 
@@ -81,10 +83,10 @@ def lap_bid_top2(vals: jax.Array):
         return lap_bid_pallas_batched(
             vals,
             jnp.zeros(vals.shape[::2], vals.dtype),
-            interpret=_default_interpret(),
+            interpret=default_interpret(),
         )
     return lap_bid_pallas(
-        vals, jnp.zeros((vals.shape[-1],), vals.dtype), interpret=_default_interpret()
+        vals, jnp.zeros((vals.shape[-1],), vals.dtype), interpret=default_interpret()
     )
 
 
@@ -97,8 +99,8 @@ def lap_bid(a: jax.Array, prices: jax.Array):
     """
     _check_bid_args("lap_bid", a, prices)
     if a.ndim == 3:
-        return lap_bid_pallas_batched(a, prices, interpret=_default_interpret())
-    return lap_bid_pallas(a, prices, interpret=_default_interpret())
+        return lap_bid_pallas_batched(a, prices, interpret=default_interpret())
+    return lap_bid_pallas(a, prices, interpret=default_interpret())
 
 
 def lap_bid_fused(cost: jax.Array, prices: jax.Array, tb_scale=0.0):
@@ -116,9 +118,9 @@ def lap_bid_fused(cost: jax.Array, prices: jax.Array, tb_scale=0.0):
     _check_bid_args("lap_bid_fused", cost, prices)
     if cost.ndim == 3:
         return lap_bid_fused_pallas_batched(
-            cost, prices, tb_scale, interpret=_default_interpret()
+            cost, prices, tb_scale, interpret=default_interpret()
         )
-    return lap_bid_fused_pallas(cost, prices, tb_scale, interpret=_default_interpret())
+    return lap_bid_fused_pallas(cost, prices, tb_scale, interpret=default_interpret())
 
 
 def migration_cost_matrix(
@@ -158,7 +160,7 @@ def migration_cost_matrix(
         jnp.asarray(slots_v, jnp.int32),
         jnp.asarray(w_u),
         jnp.asarray(w_v),
-        interpret=_default_interpret(),
+        interpret=default_interpret(),
     )
     return np.asarray(out, dtype=np.float64)  # tessalint: sync-ok(this wrapper's documented contract is a host float64 matrix; one readout of the kernel output)
 
@@ -190,7 +192,7 @@ def flash_decode(q, k, v, valid_len):
         q.shape[1] % k.shape[2] == 0,
         f"flash_decode: H={q.shape[1]} must be a multiple of KV={k.shape[2]}",
     )
-    return flash_decode_pallas(q, k, v, valid_len, interpret=_default_interpret())
+    return flash_decode_pallas(q, k, v, valid_len, interpret=default_interpret())
 
 
 def flash_attention(
@@ -215,7 +217,7 @@ def flash_attention(
         k = k.reshape(b * h, s, d)
         v = v.reshape(b * h, s, d)
         squeeze = True
-    out = flash_attention_pallas(q, k, v, causal=causal, interpret=_default_interpret())
+    out = flash_attention_pallas(q, k, v, causal=causal, interpret=default_interpret())
     if squeeze:
         out = out.reshape(b, h, s, d)
     return out

@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,7 +20,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     inter-pod DCN/ICI boundary)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes_of(mesh) -> Tuple[str, ...]:
@@ -29,4 +30,4 @@ def dp_axes_of(mesh) -> Tuple[str, ...]:
 
 def make_smoke_mesh():
     """1-device mesh for CPU smoke runs of the sharded code path."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)

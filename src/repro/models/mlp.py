@@ -96,7 +96,6 @@ def moe_ffn_sharded(p: Dict, cfg: ModelConfig, x: jax.Array) -> Tuple[jax.Array,
     mesh).
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.launch.pspec import current_rules
 
@@ -187,12 +186,12 @@ def moe_ffn_sharded(p: Dict, cfg: ModelConfig, x: jax.Array) -> Tuple[jax.Array,
         P(),
     )
     shared = p.get("shared", {"w_up": jnp.zeros((0,)), "w_down": jnp.zeros((0,))})
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(
         x,

@@ -31,9 +31,12 @@ from repro.core.fused import FusedMigrationPlanner
 from repro.core.migration import plan_migration
 from repro.core.placement import place_without_packing
 from repro.core.profiler import ThroughputProfile
+from repro.core.scheduler import TesseraeScheduler
 from repro.core.simulator import SimConfig, Simulator
 from repro.core.policies import TiresiasPolicy
 from repro.core.traces import shockwave_trace, synthetic_active_jobs
+from repro.workloads.generators import Arrivals, GangSizes, TraceRecipe, generate_trace
+from repro.workloads.schema import to_jobspecs
 
 from tests.test_churn_replay import MIN_ROUNDS, N_JOBS, ARRIVAL_RATE, SEED, RecordingScheduler
 
@@ -47,8 +50,6 @@ def _run_fused(shards, tie_break, shadow=True):
     cluster = ClusterSpec(4, 4)
     shadow_sched = None
     if shadow:
-        from repro.core.scheduler import TesseraeScheduler
-
         shadow_sched = TesseraeScheduler(
             cluster,
             TiresiasPolicy(profile, queue_base=900.0),
@@ -248,3 +249,40 @@ class TestFusedHealthTermParity:
         assert fused.matching_cost == pytest.approx(
             host.matching_cost, abs=1e-9
         )
+
+
+def test_more_shards_than_devices_raises():
+    with pytest.raises(ValueError, match="devices"):
+        FusedMigrationPlanner(shards=len(jax.devices()) + 1)
+    with pytest.raises(ValueError, match="devices"):
+        FusedMigrationPlanner(shards=0)
+
+
+def test_saturated_replay_every_round_fused():
+    """16 nodes, 96 jobs all arriving in the first round: nearly every
+    pair is dirty every round.  Each round must be served by the fused
+    program, bit-identical to the cold scipy shadow under ``tie_break``.
+    When dirty pairs and the node match ran one phase at ``eps_min`` from
+    last round's (ever-climbing) prices, rounds ran past ``max_iters`` and
+    fell back to the host."""
+    profile = ThroughputProfile()
+    cluster = ClusterSpec(16, 4)
+    # 1- and 2-GPU gangs keep 16 nodes inside the f32 budget under tie_break
+    recipe = TraceRecipe(
+        arrivals=Arrivals(rate_per_hour=96 * 12.0),
+        gangs=GangSizes((1, 2), (0.7, 0.3)),
+    )
+    trace = to_jobspecs(generate_trace(recipe, 96, seed=0), profile)
+
+    def sched(cls, **kw):
+        return cls(cluster, TiresiasPolicy(profile), profile, lap_backend="scipy",
+                   tie_break=True, **kw)
+
+    rec = sched(RecordingScheduler, shadow=sched(TesseraeScheduler), fused_fanout=True)
+    assert Simulator(cluster, trace, rec, profile).run(stop_after_rounds=12) is None
+    migrated = rec.round_log[1:]
+    assert len(migrated) == 11
+    for t, e in enumerate(migrated):
+        assert e["match_stats"].get("fused_host_fallbacks", 0) == 0, f"round {t}"
+        assert e["match_stats"]["fused_rounds"] == 1, f"round {t}"
+        assert e["plan"] == e["shadow"]["plan"], f"round {t}"

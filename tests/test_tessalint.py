@@ -69,6 +69,13 @@ class TestSyncRule:
             ("def f(x: jax.Array):\n    return x.item()\n", ".item()"),
             # f-string formatting (P2)
             ("def f(x: jax.Array):\n    return f'{x}'\n", "f-string"),
+            # a shard_map result is a device value
+            (
+                "def f(xs, g, m):\n"
+                "    y = jax.shard_map(g, mesh=m, in_specs=None, out_specs=None)(xs)\n"
+                "    return np.asarray(y)\n",
+                "asarray",
+            ),
         ],
     )
     def test_positive(self, tmp_path, body, needle):

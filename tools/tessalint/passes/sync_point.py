@@ -52,7 +52,7 @@ _PRODUCER_PREFIXES = (
     "jax.scipy.",
     "jax.experimental.",
 )
-_PRODUCER_CALLS = {"jax.vmap", "jax.pmap", "jax.grad", "jax.value_and_grad"}
+_PRODUCER_CALLS = {"jax.vmap", "jax.pmap", "jax.shard_map", "jax.grad", "jax.value_and_grad"}
 _HOST_CONVERTERS = {"numpy.asarray", "numpy.array", "numpy.ascontiguousarray"}
 _ALWAYS_SYNC = {"jax.device_get"}
 _COERCIONS = {"float", "int", "bool", "complex"}
