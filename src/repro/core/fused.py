@@ -188,101 +188,112 @@ def _fused_round(
 ):
     """One fused migration round: diff → assemble → sharded pair fan-out →
     node match → physical scatter, all one XLA program.  Everything the
-    host needs comes back in the single returned tuple (one readout)."""
+    host needs comes back in the single returned tuple (one readout).
+    Each step runs under a ``jax.named_scope`` of its name (``diff``,
+    ``assemble``, ``pair_auction``, ``node_match``, ``scatter``), which
+    the compiled program keeps in every operation's ``op_name``."""
     n_pairs = kc * kc
     eps_pair = (tb_pair if tb_pair > 0.0 else 1.0) / (kl + 1)
     eps_node = (tb_node if tb_node > 0.0 else 1.0) / (kc + 1)
 
     # --- per-node occupancy diff -> per-pair dirty mask ------------------ #
-    dirty_i = jnp.any(pi_slots != cache_pi, axis=(1, 2)) | ~cache_valid
-    dirty_j = jnp.any(pj_slots != cache_pj, axis=(1, 2)) | ~cache_valid
-    dirty = (dirty_i[:, None] | dirty_j[None, :]).reshape(n_pairs)
+    with jax.named_scope("diff"):
+        dirty_i = jnp.any(pi_slots != cache_pi, axis=(1, 2)) | ~cache_valid
+        dirty_j = jnp.any(pj_slots != cache_pj, axis=(1, 2)) | ~cache_valid
+        dirty = (dirty_i[:, None] | dirty_j[None, :]).reshape(n_pairs)
 
     # --- in-program cost assembly (exact integers in f32) ---------------- #
-    cost_p = _pair_costs(pi_slots, pj_slots, weights_scaled).reshape(n_pairs, kl, kl)
+    with jax.named_scope("assemble"):
+        cost_p = _pair_costs(pi_slots, pj_slots, weights_scaled).reshape(n_pairs, kl, kl)
 
-    # clean pairs re-enter at their cached optimum (zero bid rounds).  Dirty
-    # pairs, like the node match below, run the full epsilon schedule from
-    # the cached prices: one phase at eps_min from stale prices can take
-    # ~span/eps bid rounds, and ran past max_iters on saturated Simulator
-    # rounds from 16 nodes up.  Cached prices are shifted to a zero minimum
-    # (a uniform shift changes no bid), so they cannot climb round over
-    # round until f32 no longer resolves eps.
-    arange_kl = jnp.arange(kl, dtype=jnp.int32)
-    init_col = jnp.where(dirty[:, None], -1, cache_col_of)
-    init_prices = jnp.where(
-        cache_valid, cache_prices - cache_prices.min(axis=1, keepdims=True), 0.0
-    )
-    warm = ~dirty
+        # clean pairs re-enter at their cached optimum (zero bid rounds).
+        # Dirty pairs, like the node match below, run the full epsilon
+        # schedule from the cached prices: one phase at eps_min from stale
+        # prices can take ~span/eps bid rounds, and ran past max_iters on
+        # saturated Simulator rounds from 16 nodes up.  Cached prices are
+        # shifted to a zero minimum (a uniform shift changes no bid), so
+        # they cannot climb round over round until f32 no longer resolves
+        # eps.
+        arange_kl = jnp.arange(kl, dtype=jnp.int32)
+        init_col = jnp.where(dirty[:, None], -1, cache_col_of)
+        init_prices = jnp.where(
+            cache_valid, cache_prices - cache_prices.min(axis=1, keepdims=True), 0.0
+        )
+        warm = ~dirty
 
     # --- sharded pair fan-out -------------------------------------------- #
-    pad = (-n_pairs) % shards
-    if pad:
-        # dummy clean pairs: identity assignment, zero prices, zero cost —
-        # the while_loop exits immediately; results are sliced off below
-        cost_p = jnp.concatenate([cost_p, jnp.zeros((pad, kl, kl), cost_p.dtype)])
-        init_col = jnp.concatenate(
-            [init_col, jnp.broadcast_to(arange_kl, (pad, kl))]
-        )
-        init_prices = jnp.concatenate([init_prices, jnp.zeros((pad, kl), jnp.float32)])
-        warm = jnp.concatenate([warm, jnp.ones((pad,), bool)])
-
-    def solve_shard(cost_s, col_s, price_s, warm_s):
-        return jax.vmap(
-            lambda c, ic, ip, w: _pair_auction(
-                c, eps_pair, ip, ic, w, max_iters, use_kernel, tb_pair
+    with jax.named_scope("pair_auction"):
+        pad = (-n_pairs) % shards
+        if pad:
+            # dummy clean pairs: identity assignment, zero prices, zero cost —
+            # the while_loop exits immediately; results are sliced off below
+            cost_p = jnp.concatenate([cost_p, jnp.zeros((pad, kl, kl), cost_p.dtype)])
+            init_col = jnp.concatenate(
+                [init_col, jnp.broadcast_to(arange_kl, (pad, kl))]
             )
-        )(cost_s, col_s, price_s, warm_s)
+            init_prices = jnp.concatenate([init_prices, jnp.zeros((pad, kl), jnp.float32)])
+            warm = jnp.concatenate([warm, jnp.ones((pad,), bool)])
 
-    if shards > 1:
-        mesh = Mesh(np.array(jax.devices()[:shards]), ("pairs",))
-        solve_shard = jax.shard_map(
-            solve_shard,
-            mesh=mesh,
-            in_specs=(P("pairs"), P("pairs"), P("pairs"), P("pairs")),
-            out_specs=(P("pairs"), P("pairs"), P("pairs"), P("pairs")),
-            check_vma=False,
-        )
-    col_of, prices, iters, conv = solve_shard(cost_p, init_col, init_prices, warm)
-    if pad:
-        col_of, prices, iters, conv = (
-            col_of[:n_pairs],
-            prices[:n_pairs],
-            iters[:n_pairs],
-            conv[:n_pairs],
-        )
-        cost_p = cost_p[:n_pairs]
+        def solve_shard(cost_s, col_s, price_s, warm_s):
+            return jax.vmap(
+                lambda c, ic, ip, w: _pair_auction(
+                    c, eps_pair, ip, ic, w, max_iters, use_kernel, tb_pair
+                )
+            )(cost_s, col_s, price_s, warm_s)
+
+        if shards > 1:
+            mesh = Mesh(np.array(jax.devices()[:shards]), ("pairs",))
+            solve_shard = jax.shard_map(
+                solve_shard,
+                mesh=mesh,
+                in_specs=(P("pairs"), P("pairs"), P("pairs"), P("pairs")),
+                out_specs=(P("pairs"), P("pairs"), P("pairs"), P("pairs")),
+                check_vma=False,
+            )
+        col_of, prices, iters, conv = solve_shard(cost_p, init_col, init_prices, warm)
+        if pad:
+            col_of, prices, iters, conv = (
+                col_of[:n_pairs],
+                prices[:n_pairs],
+                iters[:n_pairs],
+                conv[:n_pairs],
+            )
+            cost_p = cost_p[:n_pairs]
 
     # --- node match over pair totals ------------------------------------- #
-    picked = jnp.take_along_axis(cost_p, col_of[:, :, None], axis=2)
-    total_scaled = picked[:, :, 0].sum(axis=1)  # (n_pairs,)
-    node_cost = total_scaled.reshape(kc, kc) + pen_scaled
-    node_col, node_prices, node_iters, node_conv = _pair_auction(
-        node_cost,
-        eps_node,
-        jnp.where(cache_valid, cache_node_prices - cache_node_prices.min(), 0.0),
-        jnp.full((kc,), -1, jnp.int32),
-        False,
-        max_iters,
-        False,  # node instance: plain jnp assembly (one LAP, no fan-out win)
-        tb_node,
-    )
+    with jax.named_scope("node_match"):
+        picked = jnp.take_along_axis(cost_p, col_of[:, :, None], axis=2)
+        total_scaled = picked[:, :, 0].sum(axis=1)  # (n_pairs,)
+        node_cost = total_scaled.reshape(kc, kc) + pen_scaled
+        node_col, node_prices, node_iters, node_conv = _pair_auction(
+            node_cost,
+            eps_node,
+            jnp.where(cache_valid, cache_node_prices - cache_node_prices.min(), 0.0),
+            jnp.full((kc,), -1, jnp.int32),
+            False,
+            max_iters,
+            False,  # node instance: plain jnp assembly (one LAP, no fan-out win)
+            tb_node,
+        )
+        matching_cost_scaled = jnp.sum(
+            jnp.take_along_axis(node_cost, jnp.maximum(node_col, 0)[:, None], axis=1)[:, 0]
+        )
 
     # --- physical scatter (argsort == host gpu_assign, inverse == host
     # node_assignment[n_cols] = n_rows) ----------------------------------- #
-    node_assignment = _inverse_assignment(node_col, kc)  # logical l -> physical k
-    gpu_assign = jnp.argsort(col_of, axis=-1).astype(jnp.int32)  # (n_pairs, kl) v -> u
-    pair_idx = node_assignment * kc + jnp.arange(kc, dtype=jnp.int32)
-    u_of_v = gpu_assign[pair_idx]  # (kc_logical, kl)
-    phys = jnp.full((kc, kl, new_slots.shape[-1]), EMPTY, new_slots.dtype)
-    phys = phys.at[node_assignment[:, None], u_of_v].set(new_slots)
+    with jax.named_scope("scatter"):
+        node_assignment = _inverse_assignment(node_col, kc)  # logical l -> physical k
+        gpu_assign = jnp.argsort(col_of, axis=-1).astype(jnp.int32)  # (n_pairs, kl) v -> u
+        pair_idx = node_assignment * kc + jnp.arange(kc, dtype=jnp.int32)
+        u_of_v = gpu_assign[pair_idx]  # (kc_logical, kl)
+        phys = jnp.full((kc, kl, new_slots.shape[-1]), EMPTY, new_slots.dtype)
+        phys = phys.at[node_assignment[:, None], u_of_v].set(new_slots)
 
-    matching_cost_scaled = jnp.sum(
-        jnp.take_along_axis(node_cost, jnp.maximum(node_col, 0)[:, None], axis=1)[:, 0]
-    )
     converged = jnp.all(conv) & node_conv
+    # pair bid rounds summed, node match's, dirty pairs, and the vmapped pair
+    # loop's trip count (it runs until its slowest pair converges)
     stats = jnp.stack(
-        [iters.sum(), node_iters, dirty.sum().astype(jnp.int32)]
+        [iters.sum(), node_iters, dirty.sum().astype(jnp.int32), iters.max()]
     )
     return (
         phys,
@@ -389,10 +400,13 @@ class FusedMigrationPlanner:
             "fused_rounds": 0,
             "fused_host_fallbacks": 0,
             "fused_budget_fallbacks": 0,
-            "fused_nonconverged_fallbacks": 0,
             "fused_dirty_pairs": 0,
             "fused_pair_instances": 0,
+            # pair auctions' bid rounds plus the node match's
             "fused_bid_iters": 0,
+            "fused_node_iters": 0,
+            # rounds of the vmapped pair loop: its slowest pair's bid rounds
+            "fused_pair_trips": 0,
             "fused_readouts": 0,
         }
 
@@ -468,26 +482,70 @@ class FusedMigrationPlanner:
         tb_pair = _tb_scale(kl, kl) if tie_break else 0.0
         tb_node = _tb_scale(kc, kc) if tie_break else 0.0
 
-        # Health terms enter the fused program EXACTLY as the host planner
-        # computes them: the same _relabel_penalties matrix (down-node
-        # domination, straggler-drain half-units, type/rack terms) is
-        # scaled and added to the in-program node cost, and its magnitude
-        # counts against the same f32 mantissa budget below — so fused
-        # plans with health terms on stay bit-identical to the host path.
-        occupied_logical = (new_logical.slots != EMPTY).any(axis=(1, 2))
-        pen = _relabel_penalties(
-            cluster, down_nodes, occupied_logical, speed_factor
-        )
-        pen_max = 0.0 if pen is None else float(pen.max())
+        with tracer.span("migrate.fused.prepare"):
+            # Health terms enter the fused program EXACTLY as the host
+            # planner computes them: the same _relabel_penalties matrix
+            # (down-node domination, straggler-drain half-units, type/rack
+            # terms) is scaled and added to the in-program node cost, and
+            # its magnitude counts against the same f32 mantissa budget
+            # below — so fused plans with health terms on stay
+            # bit-identical to the host path.
+            occupied_logical = (new_logical.slots != EMPTY).any(axis=(1, 2))
+            pen = _relabel_penalties(
+                cluster, down_nodes, occupied_logical, speed_factor
+            )
+            pen_max = 0.0 if pen is None else float(pen.max())
 
-        # f32 exactness budget: the largest scaled node-cost magnitude
-        # (each pair cell is <= 2 * MAX_PACK * 1/2 * scale, a pair total
-        # sums kl cells, plus the relabel penalty) against the finest
-        # tie-break quantum.  Outside the budget the fused program could
-        # mis-round — serve the round from the host instead.
-        quantum = min(tb_pair or 1.0, tb_node or 1.0)
-        max_abs = (2.0 * pmax * kl + pen_max) * scale
-        if max_abs / quantum >= _F32_MANTISSA:
+            # f32 exactness budget: the largest scaled node-cost magnitude
+            # (each pair cell is <= 2 * MAX_PACK * 1/2 * scale, a pair total
+            # sums kl cells, plus the relabel penalty) against the finest
+            # tie-break quantum.  Outside the budget the fused program could
+            # mis-round — serve the round from the host instead.
+            quantum = min(tb_pair or 1.0, tb_node or 1.0)
+            max_abs = (2.0 * pmax * kl + pen_max) * scale
+            in_budget = max_abs / quantum < _F32_MANTISSA
+            if in_budget:
+                common = prev.job_ids() & new_logical.job_ids()
+                pi = prev.restricted_to(common).slots.astype(np.int32)
+                pj = new_logical.restricted_to(common).slots.astype(np.int32)
+
+                max_id = max(num_gpus_of) if num_gpus_of else 0
+                weights = np.zeros(max_id + 2, np.float32)
+                for j, g in num_gpus_of.items():
+                    weights[j] = scale / (2.0 * g)  # tessalint: mantissa-ok(exact for power-of-two gpu counts; the _F32_MANTISSA budget guard above falls back to host otherwise)
+                pen_scaled = (
+                    np.zeros((kc, kc), np.float32)
+                    if pen is None
+                    else (pen * scale).astype(np.float32)
+                )
+
+                # NOT keyed on max_id: the weights table regrows as job ids
+                # climb, but a clean pair's slots pin the exact same ids (and
+                # per-id num_gpus is immutable), so its cached
+                # cost/assignment stays valid
+                key = (kc, kl, pmax, scale, tie_break)
+                if self._cache_key != key:
+                    self.invalidate()
+                if self._cache is None:
+                    cache = (
+                        jnp.zeros((kc, kl, pmax), jnp.int32),
+                        jnp.zeros((kc, kl, pmax), jnp.int32),
+                        jnp.broadcast_to(jnp.arange(kl, dtype=jnp.int32), (kc * kc, kl)),
+                        jnp.zeros((kc * kc, kl), jnp.float32),
+                        jnp.zeros((kc,), jnp.float32),
+                        jnp.asarray(False),
+                    )
+                else:
+                    cache = (*self._cache, jnp.asarray(True))
+                inputs = (
+                    jnp.asarray(pi),
+                    jnp.asarray(pj),
+                    jnp.asarray(new_logical.slots.astype(np.int32)),
+                    jnp.asarray(weights),
+                    jnp.asarray(pen_scaled),
+                )
+
+        if not in_budget:
             self.stats["fused_host_fallbacks"] += 1
             self.stats["fused_budget_fallbacks"] += 1
             self.last_fallback_reason = "fused-budget"
@@ -498,45 +556,9 @@ class FusedMigrationPlanner:
                     speed_factor,
                 )
 
-        common = prev.job_ids() & new_logical.job_ids()
-        pi = prev.restricted_to(common).slots.astype(np.int32)
-        pj = new_logical.restricted_to(common).slots.astype(np.int32)
-
-        max_id = max(num_gpus_of) if num_gpus_of else 0
-        weights = np.zeros(max_id + 2, np.float32)
-        for j, g in num_gpus_of.items():
-            weights[j] = scale / (2.0 * g)  # tessalint: mantissa-ok(exact for power-of-two gpu counts; the _F32_MANTISSA budget guard above falls back to host otherwise)
-        pen_scaled = (
-            np.zeros((kc, kc), np.float32)
-            if pen is None
-            else (pen * scale).astype(np.float32)
-        )
-
-        # NOT keyed on max_id: the weights table regrows as job ids climb,
-        # but a clean pair's slots pin the exact same ids (and per-id
-        # num_gpus is immutable), so its cached cost/assignment stays valid
-        key = (kc, kl, pmax, scale, tie_break)
-        if self._cache_key != key:
-            self.invalidate()
-        if self._cache is None:
-            cache = (
-                jnp.zeros((kc, kl, pmax), jnp.int32),
-                jnp.zeros((kc, kl, pmax), jnp.int32),
-                jnp.broadcast_to(jnp.arange(kl, dtype=jnp.int32), (kc * kc, kl)),
-                jnp.zeros((kc * kc, kl), jnp.float32),
-                jnp.zeros((kc,), jnp.float32),
-                jnp.asarray(False),
-            )
-        else:
-            cache = (*self._cache, jnp.asarray(True))
-
         with tracer.span("migrate.fused.program", kc=kc, kl=kl):
             out = _fused_round(
-                jnp.asarray(pi),
-                jnp.asarray(pj),
-                jnp.asarray(new_logical.slots.astype(np.int32)),
-                jnp.asarray(weights),
-                jnp.asarray(pen_scaled),
+                *inputs,
                 *cache,
                 kc=kc,
                 kl=kl,
@@ -546,8 +568,13 @@ class FusedMigrationPlanner:
                 tb_pair=tb_pair,
                 tb_node=tb_node,
             )
+            phys_dev, node_assign_dev, cost_dev, conv_dev, stats_dev = out[:5]
+            # the span ends when the device has finished, so the readout
+            # below is the transfer alone
+            jax.block_until_ready(  # tessalint: sync-ok(the wait for the round's one readout below, taken inside the program span so it ends at the device; still one sync per round)
+                (phys_dev, node_assign_dev, cost_dev, conv_dev, stats_dev)
+            )
         # THE readout: everything host-side comes off the device here, once
-        phys_dev, node_assign_dev, cost_dev, conv_dev, stats_dev = out[:5]
         with tracer.span("migrate.fused.readout"):
             phys, node_assignment, cost_scaled, converged, stats = jax.device_get(  # tessalint: sync-ok(THE one sanctioned readout per fused round; see BENCH_fused_decide.json)
                 (phys_dev, node_assign_dev, cost_dev, conv_dev, stats_dev)
@@ -556,7 +583,6 @@ class FusedMigrationPlanner:
 
         if not bool(converged):
             self.stats["fused_host_fallbacks"] += 1
-            self.stats["fused_nonconverged_fallbacks"] += 1
             self.last_fallback_reason = "fused-nonconverged"
             self.invalidate()
             with tracer.span(
@@ -567,24 +593,27 @@ class FusedMigrationPlanner:
                     speed_factor,
                 )
 
-        # cache stays device-resident for next round's diff / warm start
-        self._cache = (out[8], out[9], out[5], out[6], out[7])
-        self._cache_key = key
-        self.stats["fused_rounds"] += 1
-        self.stats["fused_pair_instances"] += kc * kc
-        self.stats["fused_dirty_pairs"] += int(stats[2])
-        self.stats["fused_bid_iters"] += int(stats[0]) + int(stats[1])
+        with tracer.span("migrate.fused.finish"):
+            # cache stays device-resident for next round's diff / warm start
+            self._cache = (out[8], out[9], out[5], out[6], out[7])
+            self._cache_key = key
+            self.stats["fused_rounds"] += 1
+            self.stats["fused_pair_instances"] += kc * kc
+            self.stats["fused_dirty_pairs"] += int(stats[2])
+            self.stats["fused_bid_iters"] += int(stats[0]) + int(stats[1])
+            self.stats["fused_node_iters"] += int(stats[1])
+            self.stats["fused_pair_trips"] += int(stats[3])
 
-        phys_plan = PlacementPlan(cluster, np.asarray(phys, np.int64))
-        n_mig = count_migrations(prev, phys_plan)
-        return MigrationResult(
-            phys_plan,
-            n_mig,
-            float(cost_scaled) / scale,
-            np.asarray(node_assignment, np.int64),
-            time.perf_counter() - t0,
-            "node-fused",
-        )
+            phys_plan = PlacementPlan(cluster, np.asarray(phys, np.int64))
+            n_mig = count_migrations(prev, phys_plan)
+            return MigrationResult(
+                phys_plan,
+                n_mig,
+                float(cost_scaled) / scale,
+                np.asarray(node_assignment, np.int64),
+                time.perf_counter() - t0,
+                "node-fused",
+            )
 
     def _host(
         self,

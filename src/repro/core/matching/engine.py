@@ -142,6 +142,7 @@ import numpy as np
 
 from repro.core.matching import hungarian
 from repro.core.matching.auction import masked_rect_benefit, masked_square_benefit
+from repro.obs.tracer import tracer_of
 
 #: Largest instance size solved by brute-force permutation search (k! <= 720).
 SMALLPERM_MAX_K = 6
@@ -1375,58 +1376,61 @@ def _solve_lap_batched_impl(
     if entry is not None:
         b0 = entry.instance_ids.shape[0]
         nb, nn, nm = _next_pow2(b), _next_pow2(ne), _next_pow2(me)
-        if entry.ids_dev is not None and _ids_i32_safe(inst, rids, cids):
-            # Device-resident identity matching: instance match, row/col
-            # identity match and the exact fingerprint compare run as ONE
-            # jitted program against the cached device copies of last
-            # round's identities — a single 4-tuple readout instead of
-            # three host-numpy passes plus a separate change-detection
-            # sync.  Bucket-padded inputs keep the jit signature shared
-            # across churn rounds.
-            oi_d, rp_d, cp_d, ru_d = _match_prologue_dev(
-                jnp.asarray(_bucket_vec_i32(inst, nb)),
-                entry.ids_dev[0],
-                jnp.asarray(_bucket_mat_i32(rids, nb, nn)),
-                entry.ids_dev[1],
-                jnp.asarray(_bucket_mat_i32(cids, nb, nm)),
-                entry.ids_dev[2],
-                _bucketed_bits(bits),
-                entry.fp_bits,
-            )
-            oi_h, rp_h, cp_h, ru_h = jax.device_get((oi_d, rp_d, cp_d, ru_d))  # tessalint: sync-ok(the match prologue's single documented readout; counted in stats[host_syncs])
-            context.stats["host_syncs"] += 1
-            old_idx = np.asarray(oi_h, np.int64)[:b]
-            row_pos = np.asarray(rp_h, np.int64)[:b, :ne]
-            col_pos = np.asarray(cp_h, np.int64)[:b, :me]
-            row_unchanged = np.asarray(ru_h)[:b, :ne]
-            matched = old_idx >= 0
-        else:
-            # host fallback: ids outside the int32 encoding bands
-            old_idx = _positions_in(inst[None, :], entry.instance_ids[None, :])[0]
-            safe_h = np.clip(old_idx, 0, b0 - 1)
-            row_pos = _positions_in(rids, entry.row_ids[safe_h])
-            col_pos = _positions_in(cids, entry.col_ids[safe_h])
-            matched = old_idx >= 0
-            row_pos[~matched] = -1
-            col_pos[~matched] = -1
-            # bucket-pad the compare inputs (stored fingerprints are padded
-            # at store time) so the jit signature recurs across churn rounds
-            oi_p = np.full(nb, -1, np.int64)
-            oi_p[:b] = old_idx
-            rp_p = np.full((nb, nn), -1, np.int64)
-            rp_p[:b, :ne] = row_pos
-            cp_p = np.full((nb, nm), -1, np.int64)
-            cp_p[:b, :me] = col_pos
-            row_unchanged = np.asarray(  # tessalint: sync-ok(host-fallback path for ids outside the int32 bands; one readout of the row-unchanged verdict)
-                _rows_unchanged_dev(
+        # the identity/fingerprint prologue and its readout (a jnp.pad of a
+        # new bucket shape compiles here)
+        with tracer_of(context.obs).span("lap.prologue"):
+            if entry.ids_dev is not None and _ids_i32_safe(inst, rids, cids):
+                # Device-resident identity matching: instance match, row/col
+                # identity match and the exact fingerprint compare run as ONE
+                # jitted program against the cached device copies of last
+                # round's identities — a single 4-tuple readout instead of
+                # three host-numpy passes plus a separate change-detection
+                # sync.  Bucket-padded inputs keep the jit signature shared
+                # across churn rounds.
+                oi_d, rp_d, cp_d, ru_d = _match_prologue_dev(
+                    jnp.asarray(_bucket_vec_i32(inst, nb)),
+                    entry.ids_dev[0],
+                    jnp.asarray(_bucket_mat_i32(rids, nb, nn)),
+                    entry.ids_dev[1],
+                    jnp.asarray(_bucket_mat_i32(cids, nb, nm)),
+                    entry.ids_dev[2],
                     _bucketed_bits(bits),
                     entry.fp_bits,
-                    jnp.asarray(oi_p),
-                    jnp.asarray(rp_p),
-                    jnp.asarray(cp_p),
                 )
-            )[:b, :ne]
-            context.stats["host_syncs"] += 1
+                oi_h, rp_h, cp_h, ru_h = jax.device_get((oi_d, rp_d, cp_d, ru_d))  # tessalint: sync-ok(the match prologue's single documented readout; counted in stats[host_syncs])
+                context.stats["host_syncs"] += 1
+                old_idx = np.asarray(oi_h, np.int64)[:b]
+                row_pos = np.asarray(rp_h, np.int64)[:b, :ne]
+                col_pos = np.asarray(cp_h, np.int64)[:b, :me]
+                row_unchanged = np.asarray(ru_h)[:b, :ne]
+                matched = old_idx >= 0
+            else:
+                # host fallback: ids outside the int32 encoding bands
+                old_idx = _positions_in(inst[None, :], entry.instance_ids[None, :])[0]
+                safe_h = np.clip(old_idx, 0, b0 - 1)
+                row_pos = _positions_in(rids, entry.row_ids[safe_h])
+                col_pos = _positions_in(cids, entry.col_ids[safe_h])
+                matched = old_idx >= 0
+                row_pos[~matched] = -1
+                col_pos[~matched] = -1
+                # bucket-pad the compare inputs (stored fingerprints are padded
+                # at store time) so the jit signature recurs across churn rounds
+                oi_p = np.full(nb, -1, np.int64)
+                oi_p[:b] = old_idx
+                rp_p = np.full((nb, nn), -1, np.int64)
+                rp_p[:b, :ne] = row_pos
+                cp_p = np.full((nb, nm), -1, np.int64)
+                cp_p[:b, :me] = col_pos
+                row_unchanged = np.asarray(  # tessalint: sync-ok(host-fallback path for ids outside the int32 bands; one readout of the row-unchanged verdict)
+                    _rows_unchanged_dev(
+                        _bucketed_bits(bits),
+                        entry.fp_bits,
+                        jnp.asarray(oi_p),
+                        jnp.asarray(rp_p),
+                        jnp.asarray(cp_p),
+                    )
+                )[:b, :ne]
+                context.stats["host_syncs"] += 1
         safe_b = np.clip(old_idx, 0, b0 - 1)
         ne0, me0 = entry.row_ids.shape[1], entry.col_ids.shape[1]
         rows_bij = matched & (ne == ne0) & (row_pos >= 0).all(axis=1)

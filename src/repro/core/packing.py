@@ -31,6 +31,7 @@ import numpy as np
 from repro.core.jobs import JobState
 from repro.core.matching import MatchContext, solve_lap_batched
 from repro.core.profiler import ThroughputProfile
+from repro.obs.tracer import tracer_of
 
 
 @dataclasses.dataclass
@@ -149,9 +150,11 @@ def pack_jobs(
     t0 = time.perf_counter()
     if not placed or not pending:
         return PackingResult({}, {}, 0.0, time.perf_counter() - t0, 0)
-    w = build_packing_graph(
-        placed, pending, profile, optimize_strategy, packed_ok, placed_gpu_types
-    )
+    tracer = tracer_of(context.obs if context is not None else None)
+    with tracer.span("pack.graph"):
+        w = build_packing_graph(
+            placed, pending, profile, optimize_strategy, packed_ok, placed_gpu_types
+        )
     num_edges = int((w > 0).sum())
     if num_edges == 0:
         return PackingResult({}, {}, 0.0, time.perf_counter() - t0, 0)
@@ -169,22 +172,23 @@ def pack_jobs(
     matches: Dict[int, int] = {}
     strategies: Dict[int, str] = {}
     total = 0.0
-    for i, j in zip(rows, cols):
-        if w[i, j] <= 0.0:
-            continue  # zero-weight assignment = leave unpacked
-        u, v = placed[i], pending[j]
-        matches[v.job_id] = u.job_id
-        prof_u = (
-            profile
-            if placed_gpu_types is None
-            else profile.for_gpu_type(placed_gpu_types[i])
-        )
-        _, s = prof_u.combined_weight(
-            u.spec.model, v.spec.model, optimize_strategy=optimize_strategy
-        )
-        if s != "dp":
-            strategies[u.job_id] = s
-        total += w[i, j]
+    with tracer.span("pack.apply"):
+        for i, j in zip(rows, cols):
+            if w[i, j] <= 0.0:
+                continue  # zero-weight assignment = leave unpacked
+            u, v = placed[i], pending[j]
+            matches[v.job_id] = u.job_id
+            prof_u = (
+                profile
+                if placed_gpu_types is None
+                else profile.for_gpu_type(placed_gpu_types[i])
+            )
+            _, s = prof_u.combined_weight(
+                u.spec.model, v.spec.model, optimize_strategy=optimize_strategy
+            )
+            if s != "dp":
+                strategies[u.job_id] = s
+            total += w[i, j]
     return PackingResult(
         matches, strategies, float(total), time.perf_counter() - t0, num_edges
     )

@@ -21,6 +21,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.compile_cache import CompileCounter
 from repro.core.cluster import ClusterHealth, ClusterSpec, PlacementPlan
 from repro.core.jobs import JobState
 from repro.core.matching import MatchContext
@@ -165,13 +166,22 @@ class TesseraeScheduler:
         #: graph's SHAPE but not the surviving identities.
         self.match_context = match_context if match_context is not None else MatchContext()
         self.obs = None
+        self._compile_counter = None
         if obs is not None:
             self.set_observability(obs)
 
     def set_observability(self, obs) -> None:
         """Attach (or detach, with ``None``) an observability bundle to the
         scheduler AND its matching context / fused planner, so LAP-solve
-        and fused-round spans nest under this scheduler's decide spans."""
+        and fused-round spans nest under this scheduler's decide spans.
+        While one is attached, JAX compilations count into its registry
+        (``jax.compiles.<fun_name>``)."""
+        if obs is not self.obs:
+            if self._compile_counter is not None:
+                self._compile_counter.close()
+                self._compile_counter = None
+            if obs is not None:
+                self._compile_counter = CompileCounter(obs)
         self.obs = obs
         self.match_context.obs = obs
         if self._fused_planner is not None:

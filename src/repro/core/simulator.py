@@ -405,19 +405,20 @@ class Simulator:
 
                 self._apply_events(st)
 
-                active = [
-                    s
-                    for s in st.states.values()
-                    if s.spec.arrival_time <= st.now
-                    and s.eligible_time <= st.now
-                    and not s.finished
-                ]
-                waiting = [
-                    s
-                    for s in st.states.values()
-                    if not s.finished
-                    and (s.spec.arrival_time > st.now or s.eligible_time > st.now)
-                ]
+                with tracer.span("active_scan"):
+                    active = [
+                        s
+                        for s in st.states.values()
+                        if s.spec.arrival_time <= st.now
+                        and s.eligible_time <= st.now
+                        and not s.finished
+                    ]
+                    waiting = [
+                        s
+                        for s in st.states.values()
+                        if not s.finished
+                        and (s.spec.arrival_time > st.now or s.eligible_time > st.now)
+                    ]
                 if not active and not waiting:
                     break
                 if not active:

@@ -12,7 +12,9 @@ is what lets the tests pin them on known distributions.
 
 A histogram created with ``timing=True`` is excluded from
 :meth:`MetricsRegistry.deterministic_snapshot` — wall-clock latencies
-are never part of bit-identity or CI gating.
+are never part of bit-identity or CI gating — and so is a counter
+created with ``deterministic=False`` (e.g. JAX compilations, which
+depend on what the process has already compiled, not on the seed).
 
 stdlib only; see :mod:`repro.obs.tracer` for the contract.
 """
@@ -25,11 +27,12 @@ from typing import Any, Dict, List, Optional
 
 
 class Counter:
-    __slots__ = ("name", "value")
+    __slots__ = ("name", "value", "deterministic")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, deterministic: bool = True):
         self.name = name
         self.value = 0
+        self.deterministic = deterministic
 
     def inc(self, delta: int = 1) -> None:
         self.value += delta
@@ -110,11 +113,11 @@ class MetricsRegistry:
         self._histograms: Dict[str, Histogram] = {}
 
     # -- get-or-create -------------------------------------------------- #
-    def counter(self, name: str) -> Counter:
+    def counter(self, name: str, deterministic: bool = True) -> Counter:
         with self._lock:
             c = self._counters.get(name)
             if c is None:
-                c = self._counters[name] = Counter(name)
+                c = self._counters[name] = Counter(name, deterministic)
             return c
 
     def gauge(self, name: str) -> Gauge:
@@ -160,11 +163,15 @@ class MetricsRegistry:
         }
 
     def deterministic_snapshot(self) -> Dict[str, Any]:
-        """The snapshot minus wall-clock content: counters, gauges and
-        non-timing histograms only.  Two identical seeded runs produce
+        """The snapshot minus wall-clock and process-dependent content:
+        deterministic counters, gauges and non-timing histograms only.  Two identical seeded runs produce
         equal deterministic snapshots; this is what CI gates compare."""
         return {
-            "counters": {n: c.value for n, c in sorted(self._counters.items())},
+            "counters": {
+                n: c.value
+                for n, c in sorted(self._counters.items())
+                if c.deterministic
+            },
             "gauges": {n: g.value for n, g in sorted(self._gauges.items())},
             "histograms": {
                 n: h.summary()

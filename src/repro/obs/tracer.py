@@ -21,6 +21,11 @@ Design constraints (the instrument-without-perturbing contract):
 * **no-op when disabled** — :data:`NULL_TRACER` swallows every call; the
   instrumented code paths take it by default so a run with ``obs=None``
   executes the identical decision sequence.
+* **on the profiler's clock when asked** — ``Tracer(annotate=f)`` enters
+  ``f(name)`` (a context manager, e.g. a prefixed
+  ``jax.profiler.TraceAnnotation`` injected by the caller) when a span
+  opens and exits it when the span closes, so every span also lands in
+  the device profiler's trace; this module still imports nothing of JAX.
 """
 
 from __future__ import annotations
@@ -29,7 +34,10 @@ import hashlib
 import json
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, ContextManager, Dict, List, Optional
+
+#: ``annotate(name)`` -> a context manager entered while the span is open
+Annotate = Callable[[str], ContextManager[Any]]
 
 
 class Span:
@@ -38,7 +46,7 @@ class Span:
     fingerprint, so only put *decision-derived* values here, never
     wall-clock readings (timings live on the dedicated fields)."""
 
-    __slots__ = ("name", "attrs", "children", "t0", "dur_s", "seq", "tid")
+    __slots__ = ("name", "attrs", "children", "t0", "dur_s", "seq", "tid", "_annotation")
 
     def __init__(self, name: str, attrs: Dict[str, Any], seq: int, tid: int):
         self.name = name
@@ -48,6 +56,8 @@ class Span:
         self.dur_s = 0.0
         self.seq = seq
         self.tid = tid
+        #: the open ``annotate(name)`` context, until the span closes
+        self._annotation: Optional[ContextManager[Any]] = None
 
     def annotate(self, **attrs: Any) -> None:
         """Attach attributes after the span opened (e.g. outcome counts
@@ -98,9 +108,13 @@ class Tracer:
             with tracer.span("policy_sort"):
                 ...
             sp.annotate(degrade="none")
+
+    ``annotate``, when given, is entered as each span opens and exited as
+    it closes (children first, also when an exception unwinds them).
     """
 
-    def __init__(self) -> None:
+    def __init__(self, annotate: Optional[Annotate] = None) -> None:
+        self._annotate = annotate
         self._lock = threading.Lock()
         self._local = threading.local()
         self._roots: List[Span] = []
@@ -137,16 +151,32 @@ class Tracer:
             with self._lock:
                 self._roots.append(sp)
         stack.append(sp)
+        if self._annotate is not None:
+            sp._annotation = self._annotate(name)
+            sp._annotation.__enter__()
         return _SpanContext(self, sp)
+
+    def annotation(self, name: str) -> Optional[ContextManager[Any]]:
+        """``annotate(name)`` for an interval that is not a span (e.g. a
+        compilation, whose count would make the span structure depend on
+        the process), or ``None`` without an ``annotate`` factory."""
+        return self._annotate(name) if self._annotate is not None else None
+
+    @staticmethod
+    def _end_annotation(sp: Span) -> None:
+        if sp._annotation is not None:
+            sp._annotation.__exit__(None, None, None)
+            sp._annotation = None
 
     def _close(self, sp: Span) -> None:
         sp.dur_s = (time.perf_counter() - self._epoch) - sp.t0
         stack = self._stack()
         # close any children left open by an exception, then the span
         while stack and stack[-1] is not sp:
-            stack.pop()
+            self._end_annotation(stack.pop())
         if stack:
             stack.pop()
+        self._end_annotation(sp)
 
     # ------------------------------------------------------------------ #
     def roots(self) -> List[Span]:

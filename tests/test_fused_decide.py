@@ -286,3 +286,46 @@ def test_saturated_replay_every_round_fused():
         assert e["match_stats"].get("fused_host_fallbacks", 0) == 0, f"round {t}"
         assert e["match_stats"]["fused_rounds"] == 1, f"round {t}"
         assert e["plan"] == e["shadow"]["plan"], f"round {t}"
+
+
+def test_pair_trips_are_the_slowest_pairs_bid_rounds():
+    """A cold 16x4 round: every pair is dirty and starts from zero prices.
+    ``fused_pair_trips`` is the vmapped pair loop's trip count, the most bid
+    rounds any one pair took (replayed here by vmapping ``_pair_auction``
+    over the same pairs), and the node match's rounds plus the pairs' sum
+    make ``fused_bid_iters``."""
+    import jax.numpy as jnp
+
+    from repro.core.fused import _pair_auction, _pair_costs
+    from repro.core.migration import _cost_scale
+
+    profile = ThroughputProfile()
+    cluster = ClusterSpec(16, 4)
+    jobs = synthetic_active_jobs(64, seed=3, profile=profile)
+    jobs = [j for j in jobs if j.num_gpus <= 4 or j.num_gpus % 4 == 0]
+    prev, _, _ = place_without_packing(cluster, jobs)
+    new, _, _ = place_without_packing(cluster, jobs[::-1][4:])
+    g = {j.job_id: j.num_gpus for j in jobs}
+    planner = FusedMigrationPlanner()
+    planner.plan(prev, new, g)
+    assert planner.stats["fused_rounds"] == 1
+
+    kc, kl = 16, 4
+    scale = _cost_scale(g, "auction")
+    common = prev.job_ids() & new.job_ids()
+    pi = prev.restricted_to(common).slots.astype(np.int32)
+    pj = new.restricted_to(common).slots.astype(np.int32)
+    weights = np.zeros(max(g) + 2, np.float32)
+    for j, n in g.items():
+        weights[j] = scale / (2.0 * n)
+    cost = _pair_costs(jnp.asarray(pi), jnp.asarray(pj), jnp.asarray(weights))
+    _, _, iters, conv = jax.vmap(
+        lambda c: _pair_auction(c, 1.0 / (kl + 1), jnp.zeros(kl, jnp.float32),
+                                jnp.full((kl,), -1, jnp.int32), False, 20_000, False, 0.0)
+    )(cost.reshape(kc * kc, kl, kl))
+    iters = np.asarray(iters)
+    assert bool(np.all(np.asarray(conv)))
+    assert planner.stats["fused_pair_trips"] == iters.max()
+    assert planner.stats["fused_pair_trips"] < iters.sum()
+    assert planner.stats["fused_node_iters"] > 0
+    assert planner.stats["fused_bid_iters"] == iters.sum() + planner.stats["fused_node_iters"]

@@ -25,6 +25,7 @@ The contract under test (``src/repro/obs``):
   the lint; ``time.perf_counter`` stays sanctioned).
 """
 
+import contextlib
 import json
 import textwrap
 from collections import Counter
@@ -222,6 +223,129 @@ class TestTracer:
         assert root.attrs == {"k": 1, "result": "ok"}
         assert [c.name for c in root.children] == ["inner"]
         assert root.dur_s >= root.children[0].dur_s >= 0.0
+
+    def test_annotate_enters_one_annotation_per_span_in_nesting_order(self):
+        log = []
+
+        @contextlib.contextmanager
+        def annotate(name):
+            log.append(("enter", name))
+            yield
+            log.append(("exit", name))
+
+        t = Tracer(annotate=annotate)
+        with t.span("outer"):
+            with t.span("inner"):
+                pass
+        assert log == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner"), ("exit", "outer")]
+        # an exception unwinds a child its own context never closed:
+        # the child's annotation exits before its parent's
+        log.clear()
+        with pytest.raises(RuntimeError):
+            with t.span("a"):
+                t.span("b")
+                raise RuntimeError("boom")
+        assert log == [("enter", "a"), ("enter", "b"), ("exit", "b"), ("exit", "a")]
+        with t.span("c"):
+            pass  # the stack is clean again: c is a root, not b's child
+        assert [r.name for r in t.roots()] == ["outer", "a", "c"]
+
+    def test_annotated_replay_annotates_every_span_and_decides_the_same(
+        self, profile
+    ):
+        opened, closed = Counter(), Counter()
+
+        @contextlib.contextmanager
+        def annotate(name):
+            opened[name] += 1
+            yield
+            closed[name] += 1
+
+        plain = _run(profile, fused=True)
+        obs = Observability(tracer=Tracer(annotate=annotate))
+        traced = _run(profile, obs=obs, fused=True)
+        assert _fingerprint(plain) == _fingerprint(traced)
+
+        spans = Counter()
+
+        def walk(node):
+            spans[node["name"]] += 1
+            for c in node.get("children", ()):
+                walk(c)
+
+        for root in obs.tracer.structure():
+            walk(root)
+        # compilations are annotated too, but are no spans
+        assert {k: v for k, v in opened.items() if not k.startswith("compile:")} == spans
+        assert opened == closed
+        assert {"migrate.fused.prepare", "migrate.fused.finish", "pack.graph",
+                "pack.apply", "lap.prologue", "active_scan"} <= set(spans)
+
+    def test_obs_package_imports_no_jax(self):
+        import subprocess
+        import sys
+
+        code = ("import sys, repro.obs; "
+                "sys.exit(any(m == 'jax' or m.startswith('jax.') for m in sys.modules))")
+        src = str(Path(__file__).resolve().parents[1] / "src")
+        out = subprocess.run([sys.executable, "-c", code], env={"PYTHONPATH": src},
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+
+
+# --------------------------------------------------------------------------- #
+# Compilations counted while an Observability is attached
+# --------------------------------------------------------------------------- #
+class TestCompileCounter:
+    def test_counts_a_fresh_jit_once_by_name_until_detached(self, profile):
+        import jax
+
+        log = []
+
+        def annotate(name):
+            log.append(name)
+            return contextlib.nullcontext()
+
+        obs = Observability(tracer=Tracer(annotate=annotate))
+        sched = _mk_sched(ClusterSpec(4, 4), profile)
+        sched.set_observability(obs)
+
+        def fresh_counted_fn(x):
+            return x * 3.0 + 1.0
+
+        f = jax.jit(fresh_counted_fn)
+        x7 = np.arange(7.0, dtype=np.float32)
+        f(x7)
+        f(x7)  # same shapes: no compilation
+        name = "jax.compiles.jit(fresh_counted_fn)"
+        assert obs.metrics.counter_value(name) == 1
+        assert "compile:jit(fresh_counted_fn)" in log
+        # process-dependent, so not part of the deterministic snapshot
+        assert name in obs.metrics.snapshot()["counters"]
+        assert name not in obs.metrics.deterministic_snapshot()["counters"]
+
+        sched.set_observability(None)
+        f(np.arange(3.0, dtype=np.float32))  # compiles, counted by nobody
+        assert obs.metrics.counter_value(name) == 1
+
+    def test_a_persistent_cache_load_is_not_counted(self):
+        import jax
+
+        from repro.compile_cache import CACHE_HIT_EVENT, COMPILE_EVENT, CompileCounter
+
+        obs = Observability()
+        counter = CompileCounter(obs)
+        try:
+            for hit in (True, False):
+                jax.monitoring.record_scalar(COMPILE_EVENT, 0.0, fun_name="jit(g)")
+                if hit:
+                    jax.monitoring.record_event(CACHE_HIT_EVENT)
+                jax.monitoring.record_event_time_span(
+                    COMPILE_EVENT, 0.0, 1.0, fun_name="jit(g)")
+        finally:
+            counter.close()
+        assert obs.metrics.counter_value("jax.compiles.jit(g)") == 1
 
 
 # --------------------------------------------------------------------------- #

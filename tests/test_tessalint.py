@@ -53,6 +53,9 @@ class TestSyncRule:
             ("def f(x: jax.Array):\n    return np.asarray(x)\n", "asarray"),
             # device_get is ALWAYS a flagged sync point
             ("def f(x: jax.Array):\n    return jax.device_get(x)\n", "device_get"),
+            # ...and so is the wait for the device
+            ("def f(x: jax.Array):\n    return jax.block_until_ready(x)\n",
+             "block_until_ready"),
             # float() coercion of a produced device value (taint chain)
             (
                 "def f():\n    t = jnp.sum(jnp.ones(3))\n    u = t * 2\n"

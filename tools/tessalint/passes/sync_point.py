@@ -11,8 +11,9 @@ In modules the manifest declares device-resident, flag:
 * ``np.asarray`` / ``np.array`` / ``np.ascontiguousarray`` on a value
   that (transitively) came from ``jax.numpy`` / ``jax.lax`` / another
   device producer;
-* ``jax.device_get`` — ALWAYS flagged: every sanctioned readout is
-  pragma-annotated, so the set of syncs is closed under review;
+* ``jax.device_get`` and ``jax.block_until_ready`` — ALWAYS flagged:
+  every sanctioned readout or wait is pragma-annotated, so the set of
+  syncs is closed under review;
 * ``.item()`` / ``.tolist()`` and ``float()/int()/bool()/complex()``
   coercions of device values;
 * ``if`` / ``while`` tests and ``for`` iteration over device values
@@ -54,7 +55,7 @@ _PRODUCER_PREFIXES = (
 )
 _PRODUCER_CALLS = {"jax.vmap", "jax.pmap", "jax.shard_map", "jax.grad", "jax.value_and_grad"}
 _HOST_CONVERTERS = {"numpy.asarray", "numpy.array", "numpy.ascontiguousarray"}
-_ALWAYS_SYNC = {"jax.device_get"}
+_ALWAYS_SYNC = {"jax.device_get", "jax.block_until_ready"}
 _COERCIONS = {"float", "int", "bool", "complex"}
 _FORMATTERS = {"print", "repr", "str"}
 _SYNC_METHODS = {"item", "tolist", "block_until_ready"}
@@ -183,7 +184,7 @@ def run(ctx: FileContext) -> List[Finding]:
                 if q in _ALWAYS_SYNC:
                     flag(
                         node,
-                        "jax.device_get is a device→host sync point",
+                        f"{q} is a device→host sync point",
                         "if this is THE sanctioned readout, annotate it: "
                         "# tessalint: sync-ok(<why this readout is in budget>)",
                     )
