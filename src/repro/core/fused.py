@@ -36,6 +36,9 @@ round:
   dirty pairs and the node match run the full epsilon schedule seeded
   with the cached prices (valid for any initial prices on square
   instances), shifted to a zero minimum so f32 keeps resolving ``eps``.
+  Both take the dense bid round, which makes the same decisions with no
+  gather or scatter: batched gathers and scatters run an element at a
+  time on the TPU (vmapped over all pairs, slices of 4 elements).
 
 Exactness / parity contract: scaled costs are integers and the tie-break
 scale a power of two, so while ``k_l * scale / tb_scale < 2^24`` every
@@ -132,7 +135,7 @@ def _pair_auction(cost, eps_min, init_prices, init_col_of, warm, max_iters, use_
     eps_min = jnp.asarray(eps_min, jnp.float32)
     span = jnp.maximum(jnp.max(jnp.abs(cost)), 1.0)
     eps0 = jnp.where(warm, eps_min, jnp.maximum(span / 4.0, eps_min))
-    bid_round = _make_bid_round(cost, n, _pair_top2(use_kernel, tb))
+    bid_round = _make_bid_round(cost, n, _pair_top2(use_kernel, tb), dense=True)
 
     def cond(state):
         _, col_of, eps, it = state

@@ -80,10 +80,12 @@ class AuctionResult(NamedTuple):
 
 
 def _top2(vals: jax.Array):
-    """Row-wise (best value, best index, second-best value)."""
+    """Row-wise (best value, best index, second-best value).  The best
+    value is the row max, not a gather at ``best_j``: the same number up to
+    the sign of a zero, which cancels in ``best - second``."""
     best_j = jnp.argmax(vals, axis=-1)
     n = vals.shape[-1]
-    best_v = jnp.take_along_axis(vals, best_j[..., None], axis=-1)[..., 0]
+    best_v = jnp.max(vals, axis=-1)
     masked = jnp.where(
         jax.nn.one_hot(best_j, n, dtype=bool), _NEG, vals
     )
@@ -229,32 +231,50 @@ def _pick_top2(use_kernel: bool):
     return lambda benefit, prices: _top2(benefit - prices[None, :])
 
 
-def _make_bid_round(benefit: jax.Array, m: int, top2):
+def _inverse_assignment_dense(assign: jax.Array, out_size: int) -> jax.Array:
+    """:func:`_inverse_assignment` without its scatter, as a compare and a
+    reduce: ``inv[j] = max_i where(assign[i] == j, i, -1)``.  The map is
+    injective, so at most one ``i`` matches."""
+    k = assign.shape[0]
+    hit = assign[None, :] == jnp.arange(out_size, dtype=assign.dtype)[:, None]
+    return jnp.max(jnp.where(hit, jnp.arange(k, dtype=jnp.int32), -1), axis=1)
+
+
+def _make_bid_round(benefit: jax.Array, m: int, top2, dense: bool = False):
     """Jacobi bid round over an (n, m) benefit matrix (square or rect):
     every unassigned person bids for its best object; objects take the
-    highest bid.  Returns ``(prices, col_of) -> (prices, col_of)``."""
+    highest bid.  Returns ``(prices, col_of) -> (prices, col_of)``.
+
+    ``dense`` makes the same decisions bit for bit with no gather and no
+    scatter: the price at each bidder's best object is read through the
+    bid's one-hot mask, and both inversions of the assignment are
+    :func:`_inverse_assignment_dense`.  On the TPU, batched gathers and
+    scatters run an element at a time, while these (n, m) compares and
+    reductions fuse.  The fused migrate program's auctions take it; the
+    engine's keep the indexed form."""
     n = benefit.shape[0]
+    invert = _inverse_assignment_dense if dense else _inverse_assignment
 
     def bid_round(prices, col_of, eps):
         unassigned = col_of < 0
         best_v, best_j, second_v = top2(benefit, prices)
         incr = best_v - second_v + eps
+        best = jax.nn.one_hot(best_j, m, dtype=bool)
         # Bid value person i offers for its best object.
-        offer = prices[best_j] + incr
+        if dense:
+            offer = jnp.max(jnp.where(best, prices[None, :], -jnp.inf), axis=1) + incr
+        else:
+            offer = prices[best_j] + incr
         # (n_persons, n_objects) matrix of offers; -inf where no bid.
-        bids = jnp.where(
-            unassigned[:, None] & jax.nn.one_hot(best_j, m, dtype=bool),
-            offer[:, None],
-            _NEG,
-        )
+        bids = jnp.where(unassigned[:, None] & best, offer[:, None], _NEG)
         has_bid = jnp.any(bids > _NEG / 2, axis=0)
         winner = jnp.argmax(bids, axis=0)
         new_price = jnp.max(bids, axis=0)
         prices = jnp.where(has_bid, new_price, prices)
         # Recompute owners: objects with a bid switch to the winner.
-        row_of_prev = _inverse_assignment(col_of, m)
+        row_of_prev = invert(col_of, m)
         row_of = jnp.where(has_bid, winner, row_of_prev)
-        col_of = _inverse_assignment(row_of, n)
+        col_of = invert(row_of, n)
         return prices, col_of
 
     return bid_round

@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.fused import lower_fused_round
 from repro.kernels import lap_bid
+from tests.test_fused_decide import index_ops_by_scope
 
 HBM_BYTES = 16 * 10**9  # one v5e chip
 KC, KL, PMAX = 512, 4, 2  # 2048 GPUs
@@ -99,3 +100,9 @@ def test_fused_round_compiles_at_2048_gpus(one_chip, monkeypatch, use_kernel):
         KC, KL, PMAX, N_WEIGHTS, use_kernel=use_kernel, sharding=one_chip
     ).compile()
     _check(compiled, use_kernel)
+    # the 4x4 pair auctions and the 512-node match take the dense bid
+    # round: the node match keeps only the gathers of the picked pair
+    # totals and of the matching cost, outside its bid loop
+    ops = index_ops_by_scope(compiled.as_text())
+    assert "pair_auction" not in ops, ops
+    assert ops.get("node_match") == 2, ops

@@ -20,6 +20,8 @@ churn-replay differential gate:
   pair axis preserves the full physical relabelling.
 """
 
+import re
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -329,3 +331,73 @@ def test_pair_trips_are_the_slowest_pairs_bid_rounds():
     assert planner.stats["fused_pair_trips"] < iters.sum()
     assert planner.stats["fused_node_iters"] > 0
     assert planner.stats["fused_bid_iters"] == iters.sum() + planner.stats["fused_node_iters"]
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 4, 8, 16, 64, 512])
+def test_dense_bid_round_matches_indexed(k):
+    """The fused program's dense bid round makes the indexed round's
+    decisions bit for bit, trip by trip: tie-heavy integer costs, partial
+    starting assignments, warm (non-zero) prices, with and without the
+    positional tie-break, and a falling eps as the scaling schedule has.
+    Pair sides (gpus per node) and the node match's side (512 nodes)."""
+    import jax.numpy as jnp
+
+    from repro.core.fused import _pair_top2, _tb_scale
+    from repro.core.matching.auction import _make_bid_round
+
+    rng = np.random.default_rng(1000 + k)
+    batch = max(2, 512 // k)
+    cost = jnp.asarray(rng.integers(0, 3, (batch, k, k)).astype(np.float32))
+    prices0 = jnp.asarray(rng.integers(0, 4, (batch, k)).astype(np.float32))
+    # a partial injective assignment per instance: a random permutation
+    # with about half of its persons unassigned
+    perm = np.argsort(rng.random((batch, k)), axis=1).astype(np.int32)
+    col0 = jnp.asarray(np.where(rng.random((batch, k)) < 0.5, -1, perm))
+
+    def rounds(tb, dense):
+        return jax.jit(jax.vmap(
+            lambda c, p, a, e: _make_bid_round(c, k, _pair_top2(False, tb), dense)(p, a, e),
+            in_axes=(0, 0, 0, None)))
+
+    for tb in (0.0, _tb_scale(k, k)):
+        indexed, dense = rounds(tb, False), rounds(tb, True)
+        prices, col_of = prices0, col0
+        for trip in range(30):
+            eps = jnp.float32(max(2.0 / 5**trip, 1.0 / (k + 1)) * (tb or 1.0))
+            p_ref, c_ref = indexed(cost, prices, col_of, eps)
+            p_new, c_new = dense(cost, prices, col_of, eps)
+            np.testing.assert_array_equal(
+                np.asarray(p_new).view(np.int32), np.asarray(p_ref).view(np.int32),
+                err_msg=f"tb={tb} trip {trip}: prices",
+            )
+            np.testing.assert_array_equal(c_new, c_ref, err_msg=f"tb={tb} trip {trip}: col_of")
+            prices, col_of = p_ref, c_ref
+        # the trips did bid: prices rose and assignments filled
+        assert np.asarray(col_of >= 0).sum() > np.asarray(col0 >= 0).sum()
+
+
+def index_ops_by_scope(hlo_text):
+    """``{scope: number of gather and scatter instructions}`` of a compiled
+    ``_fused_round``, by :func:`bench.program_spans.scope_map`."""
+    from bench.program_spans import scope_map
+
+    smap = scope_map(hlo_text)
+    out = {}
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", line)
+        if m and ("gather(" in line or "scatter(" in line):
+            scope = smap.get(m.group(1))
+            out[scope] = out.get(scope, 0) + 1
+    return out
+
+
+def test_pair_auction_compiles_without_gather_or_scatter():
+    """At 16 nodes x 4 GPUs the compiled fused round keeps no gather or
+    scatter inside ``pair_auction`` (the pair fan-out takes the dense bid
+    round), while ``node_match`` keeps its own (the picked pair totals)."""
+    from repro.core.fused import lower_fused_round
+
+    ops = index_ops_by_scope(lower_fused_round(16, 4, 2, 66).compile().as_text())
+    assert "pair_auction" not in ops, ops
+    assert ops.get("node_match", 0) > 0, ops
+    assert ops.get("scatter", 0) > 0, ops
