@@ -22,16 +22,22 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from bench import harness, reference  # noqa: E402
 
 
-def control_readings(rounds, gangs):
+def control_readings(rounds, gangs, types=None, racks=None):
+    """The control's numbers on the window's rounds, on a cluster with GPU
+    types or racks under the reference's type and rack rules."""
     cost_gap = plan_gap = 0.0
     invalid = 0
     for r in rounds:
         mig = r.migration
-        opt, _ = reference.relabel(mig.prev, mig.logical, gangs)
-        cost, phys = reference.relabel(mig.prev, mig.logical, gangs, "bfloat16")
+        opt, _, _ = reference.relabel(mig.prev, mig.logical, gangs, types=types, racks=racks)
+        cost, phys, node_map = reference.relabel(mig.prev, mig.logical, gangs, "bfloat16",
+                                                 types=types, racks=racks)
         cost_gap = max(cost_gap, abs(cost - opt))
-        plan_gap = max(plan_gap, abs(reference.plan_cost(mig.prev, phys, gangs, mig.logical) - opt))
-        invalid += bool(reference.plan_problems(phys, r.active, gangs, mig.logical))
+        exact = reference.plan_cost(mig.prev, phys, gangs, mig.logical,
+                                    node_map=node_map, racks=racks)
+        plan_gap = max(plan_gap, abs(exact - opt))
+        invalid += bool(reference.plan_problems(phys, r.active, gangs, mig.logical,
+                                                types=types, node_map=node_map))
     return {"cost_gap": cost_gap, "plan_cost_gap": plan_gap, "invalid_plans": invalid}
 
 
@@ -53,7 +59,8 @@ def main(argv=None) -> int:
     for seed in (int(s) for s in args.seeds.split(",")):
         res = harness.run(spec, seed, args.seconds, False, time.perf_counter(), device)
         program = {k: c["value"] for k, c in res["checks"].items()}
-        control = control_readings(res["_rounds"], res["_gangs"])
+        control = control_readings(res["_rounds"], res["_gangs"],
+                                   *harness.layout(spec["config"]["cluster"]))
         for k in harness.LIMITS:
             lower[k] = max(lower[k], program[k])
             upper[k] = min(upper[k], control[k])

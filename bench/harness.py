@@ -28,7 +28,7 @@ import os
 import shutil
 import sys
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -51,6 +51,8 @@ TRACE_ROUNDS = 3
 #: JAX's event around every backend compilation, a persistent-cache load too
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: the keys a configuration's ``cluster`` may hold; the last two are optional
+CLUSTER_KEYS = ("num_nodes", "gpus_per_node", "node_gpu_types", "nodes_per_rack")
 
 
 class NoChip(Exception):
@@ -130,6 +132,8 @@ class Migration:
     prev: np.ndarray
     logical: np.ndarray
     cost: float
+    #: ``node_map[l]``: the physical node that hosts logical node ``l``
+    node_map: Optional[np.ndarray]
 
 
 @dataclasses.dataclass
@@ -168,8 +172,10 @@ class Recorder:
             mig = None
             if self._pending is not None:
                 prev, logical, res = self._pending
+                node_map = (None if res.node_assignment is None
+                            else np.array(res.node_assignment, np.int64))
                 mig = Migration(prev.slots.copy(), logical.slots.copy(),
-                                float(res.matching_cost))
+                                float(res.matching_cost), node_map)
             self.rounds.append(Round(
                 dt, dict(d.timings), dict(d.match_stats), d.degrade_reason,
                 np.fromiter((j.job_id for j in active_jobs), np.int64, len(active_jobs)),
@@ -199,18 +205,63 @@ class Recorder:
         FusedMigrationPlanner.plan = self._plan
 
 
+def layout(cluster: Dict) -> Tuple[Optional[np.ndarray], Optional[np.ndarray]]:
+    """Every node's GPU type and rack as a configuration's ``cluster``
+    states them, each None where it states none.
+
+    ``node_gpu_types`` is a run-length list, ``[["a100", 256], ["v100",
+    256]]``: nodes 0-255 are A100 nodes, 256-511 V100 nodes.  With
+    ``nodes_per_rack`` r > 0, nodes ``[k*r, (k+1)*r)`` form rack k.  Raises
+    on a key outside ``CLUSTER_KEYS`` or counts that do not cover the
+    nodes."""
+    unknown = sorted(set(cluster) - set(CLUSTER_KEYS))
+    if unknown:
+        raise ValueError(f"unknown cluster keys {unknown}; known: {list(CLUSTER_KEYS)}")
+    n = cluster["num_nodes"]
+    types = racks = None
+    if "node_gpu_types" in cluster:
+        runs = cluster["node_gpu_types"]
+        if not all(isinstance(t, str) and type(c) is int and c > 0 for t, c in runs):
+            raise ValueError(f"node_gpu_types is not a list of [type, count]: {runs}")
+        types = np.array([t for t, c in runs for _ in range(c)])
+        if types.size != n:
+            raise ValueError(f"node_gpu_types counts sum to {types.size}, not {n} nodes")
+    if "nodes_per_rack" in cluster:
+        per_rack = cluster["nodes_per_rack"]
+        if type(per_rack) is not int or per_rack < 0:
+            raise ValueError(f"nodes_per_rack is not a whole number >= 0: {per_rack!r}")
+        if per_rack:
+            racks = np.arange(n) // per_rack
+    return types, racks
+
+
+def cluster_spec(cluster: Dict):
+    """The program's ``ClusterSpec`` for a configuration's ``cluster``."""
+    from repro.core.cluster import ClusterSpec
+    from repro.core.profiler import GPU_TYPES
+
+    types, _ = layout(cluster)
+    kw = {}
+    if types is not None:
+        unknown = sorted(set(types.tolist()) - set(GPU_TYPES))
+        if unknown:
+            raise ValueError(f"GPU types {unknown} not in the profile: {sorted(GPU_TYPES)}")
+        kw["node_gpu_types"] = tuple(types.tolist())
+    if "nodes_per_rack" in cluster:
+        kw["nodes_per_rack"] = cluster["nodes_per_rack"]
+    return ClusterSpec(cluster["num_nodes"], cluster["gpus_per_node"], **kw)
+
+
 def build(config: Dict, traffic_mix: Dict, seed: int):
     """The system under test for one cell and seed, and every job's gang."""
     from repro.core import policies
-    from repro.core.cluster import ClusterSpec
     from repro.core.profiler import ThroughputProfile
     from repro.core.scheduler import TesseraeScheduler
     from repro.core.simulator import SimConfig, Simulator
 
     specs, gangs = traffic.job_specs(config, traffic_mix, seed)
     profile = ThroughputProfile()
-    cl = config["cluster"]
-    cluster = ClusterSpec(cl["num_nodes"], cl["gpus_per_node"])
+    cluster = cluster_spec(config["cluster"])
     policy = getattr(policies, config["policy"])(profile)
     sched = TesseraeScheduler(cluster, policy, profile, **config["scheduler"])
     sim = Simulator(cluster, specs, sched, profile, SimConfig(**config["sim"]))
@@ -223,23 +274,34 @@ def one_round(sim, annotate) -> None:
             raise RuntimeError("the trace ran out of jobs before the window closed")
 
 
-def check(rounds: List[Round], gangs: Dict[int, int]) -> Dict[str, Dict]:
+def check(rounds: List[Round], gangs: Dict[int, int],
+          types: Optional[np.ndarray] = None,
+          racks: Optional[np.ndarray] = None) -> Dict[str, Dict]:
     """Each window round against the reference: the relabelling's reported
     cost and its plan's exact cost against the optimum, and the plan's
-    validity.  Returns every number compared beside its limit."""
+    validity; on a cluster with GPU types or racks (``layout``), under the
+    reference's type and rack rules, and the plan read through the
+    program's node map.  Returns every number compared beside its limit."""
     cost_gap = plan_gap = 0.0
     invalid = 0
+    typed = types is not None or racks is not None
     for t, r in enumerate(rounds):
         mig = r.migration
+        node_map = mig.node_map if typed and mig is not None else None
         problems = reference.plan_problems(
-            r.plan, r.active, gangs, None if mig is None else mig.logical
+            r.plan, r.active, gangs, None if mig is None else mig.logical,
+            types=types, node_map=node_map,
         )
         if mig is None:
             problems.append("no relabelling recorded")
+        elif typed and node_map is None:
+            problems.append("no node map recorded")
         else:
-            opt, _ = reference.relabel(mig.prev, mig.logical, gangs)
+            opt, _, _ = reference.relabel(mig.prev, mig.logical, gangs,
+                                          types=types, racks=racks)
             cost_gap = max(cost_gap, abs(mig.cost - opt))
-            exact = reference.plan_cost(mig.prev, r.plan, gangs, mig.logical)
+            exact = reference.plan_cost(mig.prev, r.plan, gangs, mig.logical,
+                                        node_map=node_map, racks=racks)
             plan_gap = max(plan_gap, abs(exact - opt))
         if problems:
             invalid += 1
@@ -362,7 +424,7 @@ def run(spec: Dict, seed: int, seconds: float, trace: bool, t_start: float,
                       window_s=record["trace"]["window_s"])
         breakdown = record["trace"]["breakdown"]
 
-    checks = check(rounds, gangs)
+    checks = check(rounds, gangs, *layout(spec["config"]["cluster"]))
     metrics = {}
     for m in spec["per_layer" if trace else "end_to_end"]:
         value = metric_reader(m["name"])(record)

@@ -359,7 +359,8 @@ def run(spec: Dict, seed: int, seconds: float, profile: bool, t_start: float,
             metrics[m["name"]] = value
     return {
         "correct": all(c["value"] <= c["limit"]
-                       for c in harness.check(rounds, gangs).values()),
+                       for c in harness.check(
+                           rounds, gangs, *harness.layout(spec["config"]["cluster"])).values()),
         "attempted": len(rounds),
         "metrics": metrics,
         "compiled_in_window": compiles1["compiled"] - compiles0["compiled"],

@@ -13,10 +13,20 @@ Written from the paper's definitions (Tesserae, arXiv 2508.04953, Algorithms
   the relabelling is the node map of least total pair cost (solved by
   ``scipy.optimize.linear_sum_assignment``).
 
-Every cost is a sum of a few multiples of 1/64 at these gang sizes, so the
-float64 arithmetic below is exact and the optimum is compared for equality.
-``precision="bfloat16"`` computes the same relabelling with every cost and
-every sum rounded to bfloat16: the control, which the comparison must reject.
+A cluster may give every node a GPU type and a rack (``types[k]``,
+``racks[k]``).  Logical node ``l`` was laid out for physical node ``l``'s
+GPU type and rack, and the relabelling keeps to two rules:
+
+* A logical node may be hosted only on a physical node of its own GPU type:
+  a node pair of two types is not in the assignment at all.
+* Hosting a logical node on a node of another rack costs 1/2, whether the
+  node is empty or not; the relabelling's cost includes it.
+
+Every cost is a sum of a few multiples of 1/64 at these gang sizes, and of
+halves, so the float64 arithmetic below is exact and the optimum is compared
+for equality.  ``precision="bfloat16"`` computes the same relabelling with
+every cost and every sum rounded to bfloat16, rack halves included: the
+control, which the comparison must reject.
 """
 
 from __future__ import annotations
@@ -27,6 +37,8 @@ import numpy as np
 from scipy.optimize import linear_sum_assignment
 
 EMPTY = -1
+#: the cost of hosting a logical node on a node of another rack
+CROSS_RACK = 0.5
 
 
 def weight_table(gangs: Dict[int, int]) -> np.ndarray:
@@ -129,14 +141,39 @@ def gpu_map(cost: np.ndarray) -> np.ndarray:
     return u_of_v
 
 
+def node_assignment(
+    node_cost: np.ndarray, types: Optional[np.ndarray]
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Physical rows and logical columns of the node map of least total
+    cost, rows ascending; with ``types``, one assignment for each GPU type
+    over that type's nodes, so that no pair of two types is in it."""
+    if types is None:
+        return linear_sum_assignment(node_cost)
+    types = np.asarray(types)
+    rows, cols = [], []
+    for t in np.unique(types):
+        nodes = np.flatnonzero(types == t)
+        r, c = linear_sum_assignment(node_cost[np.ix_(nodes, nodes)])
+        rows.append(nodes[r])
+        cols.append(nodes[c])
+    rows, cols = np.concatenate(rows), np.concatenate(cols)
+    order = np.argsort(rows)
+    return rows[order], cols[order]
+
+
 def relabel(
     prev: np.ndarray,
     logical: np.ndarray,
     gangs: Dict[int, int],
     precision: str = "float64",
-) -> Tuple[float, np.ndarray]:
-    """Algorithm 2: ``(matching cost, physical plan)`` of the relabelling of
-    ``logical`` onto the physical nodes of ``prev`` that moves least."""
+    types: Optional[np.ndarray] = None,
+    racks: Optional[np.ndarray] = None,
+) -> Tuple[float, np.ndarray, np.ndarray]:
+    """Algorithm 2: ``(matching cost, physical plan, node map)`` of the
+    relabelling of ``logical`` onto the physical nodes of ``prev`` that moves
+    least; ``node_map[l]`` is the physical node that hosts logical node
+    ``l``.  ``types`` keeps every logical node on its own GPU type, and
+    ``racks`` adds 1/2 to the cost for every rack crossing."""
     ar = _Arith(precision)
     w = weight_table(gangs)
     keep = common_jobs(prev, logical)
@@ -144,29 +181,48 @@ def relabel(
     kc, kl = prev.shape[:2]
     costs = pair_costs(pi, pj, w)  # (kc, kc, kl, kl)
     node_cost = min_assignment(costs.reshape(kc * kc, kl, kl), ar).reshape(kc, kc)
-    rows, cols = linear_sum_assignment(node_cost)  # physical row -> logical col
+    if racks is not None:
+        racks = np.asarray(racks)
+        crossing = CROSS_RACK * (racks[:, None] != racks[None, :])
+        node_cost = np.asarray(ar.add(node_cost, crossing), np.float64)
+    rows, cols = node_assignment(node_cost, types)  # physical row -> logical col
     total = ar.total(node_cost[rows, cols])
     phys = np.full_like(logical, EMPTY)
     for i, j in zip(rows, cols):
         phys[i, gpu_map(ar.cast(costs[i, j]))] = logical[j]
-    return total, phys
+    node_map = np.empty(kc, np.int64)
+    node_map[cols] = rows
+    return total, phys, node_map
 
 
-def plan_cost(prev: np.ndarray, phys: np.ndarray, gangs: Dict[int, int], logical: np.ndarray) -> float:
+def plan_cost(
+    prev: np.ndarray,
+    phys: np.ndarray,
+    gangs: Dict[int, int],
+    logical: np.ndarray,
+    node_map: Optional[np.ndarray] = None,
+    racks: Optional[np.ndarray] = None,
+) -> float:
     """Exact cost of going from ``prev`` to the physical plan ``phys``: every
     physical GPU's old and new content, over the jobs common to ``prev`` and
-    the logical plan."""
+    the logical plan; with ``racks``, plus 1/2 for each logical node that the
+    plan's ``node_map`` hosts in another rack."""
     w = weight_table(gangs)
     keep = common_jobs(prev, logical)
     a, b = restrict(prev, keep), restrict(phys, keep)
-    return float(np.diagonal(_gpu_costs(a, b, w), axis1=-2, axis2=-1).sum())
+    cost = float(np.diagonal(_gpu_costs(a, b, w), axis1=-2, axis2=-1).sum())
+    if racks is not None:
+        if node_map is None:
+            raise ValueError("the rack term needs the plan's node map")
+        racks = np.asarray(racks)
+        cost += CROSS_RACK * int((racks[np.asarray(node_map)] != racks).sum())
+    return cost
 
 
-def _node_signatures(slots: np.ndarray) -> List[Tuple[int, ...]]:
+def _node_keys(slots: np.ndarray) -> List[Tuple[Tuple[int, ...], ...]]:
+    """Each node's content, its GPUs' job sets in sorted order."""
     per_gpu = np.sort(slots, axis=-1)  # a GPU's jobs as a set
-    return sorted(
-        tuple(sorted(map(tuple, node.tolist()))) for node in per_gpu
-    )
+    return [tuple(sorted(map(tuple, node.tolist()))) for node in per_gpu]
 
 
 def plan_problems(
@@ -174,11 +230,16 @@ def plan_problems(
     active: np.ndarray,
     gangs: Dict[int, int],
     logical: Optional[np.ndarray] = None,
+    types: Optional[np.ndarray] = None,
+    node_map: Optional[np.ndarray] = None,
 ) -> List[str]:
     """What is wrong with one round's plan (empty when it is valid):
     unknown jobs, a job twice on one GPU, a gang on another number of GPUs,
     a gang not consolidated (one node, or whole nodes), and, for a migrated
-    round, a plan that is not a node-and-GPU relabelling of ``logical``."""
+    round, a plan that is not a node-and-GPU relabelling of ``logical``.
+    With the plan's ``node_map``: a logical node that is not where the map
+    puts it, and, with ``types``, a logical node hosted on a node of another
+    GPU type."""
     kc, kl, _ = slots.shape
     out: List[str] = []
     node, _, _ = np.nonzero(slots != EMPTY)
@@ -203,6 +264,20 @@ def plan_problems(
     partial = (g_of_pair > kl) & (per_node != kl)
     if split.any() or partial.any():
         out.append(f"{int(split.sum() + partial.sum())} job-node pairs not consolidated")
-    if logical is not None and _node_signatures(slots) != _node_signatures(logical):
+    if logical is not None and sorted(_node_keys(slots)) != sorted(_node_keys(logical)):
         out.append("not a relabelling of the logical plan")
+    if logical is not None and node_map is not None:
+        node_map = np.asarray(node_map)
+        if not np.array_equal(np.sort(node_map), np.arange(kc)):
+            out.append("the node map is not one-to-one")
+            return out
+        hosted, laid_out = _node_keys(slots), _node_keys(logical)
+        astray = sum(hosted[k] != laid_out[lo] for lo, k in enumerate(node_map.tolist()))
+        if astray:
+            out.append(f"{astray} logical nodes not where the node map puts them")
+        if types is not None:
+            types = np.asarray(types)
+            crossed = int((types[node_map] != types).sum())
+            if crossed:
+                out.append(f"{crossed} logical nodes hosted on a node of another GPU type")
     return out

@@ -4,6 +4,7 @@ every fault planted in the timed path."""
 
 import copy
 import io
+import itertools
 import json
 import os
 import re
@@ -165,12 +166,12 @@ def _fused_plan_patched(monkeypatch, change):
     monkeypatch.setattr(FusedMigrationPlanner, "plan", patched)
 
 
-def _result(prev, plan_slots, cost):
+def _result(prev, plan_slots, cost, node_map=None):
     from repro.core.cluster import PlacementPlan, count_migrations
     from repro.core.migration import MigrationResult
 
     phys = PlacementPlan(prev.cluster, plan_slots)
-    return MigrationResult(phys, count_migrations(prev, phys), cost, None, 0.0, "planted")
+    return MigrationResult(phys, count_migrations(prev, phys), cost, node_map, 0.0, "planted")
 
 
 def test_the_lower_precision_control_is_not_correct(monkeypatch):
@@ -179,7 +180,7 @@ def test_the_lower_precision_control_is_not_correct(monkeypatch):
     test runs where sums do not: 128 nodes."""
 
     def control(prev, logical, gangs, _res):
-        cost, phys = reference.relabel(prev.slots, logical.slots, gangs, "bfloat16")
+        cost, phys, _ = reference.relabel(prev.slots, logical.slots, gangs, "bfloat16")
         return _result(prev, phys, cost)
 
     _fused_plan_patched(monkeypatch, control)
@@ -248,7 +249,7 @@ def test_the_reference_agrees_with_the_programs_host_planner(kc, kl):
         a, b = random_plan(), random_plan()
         want = plan_migration(PlacementPlan(cluster, a.copy()), PlacementPlan(cluster, b.copy()),
                               gangs, algorithm="node", backend="scipy")
-        cost, phys = reference.relabel(a, b, gangs)
+        cost, phys, _ = reference.relabel(a, b, gangs)
         assert cost == want.matching_cost
         assert reference.plan_cost(a, phys, gangs, b) == cost
         assert reference.plan_cost(a, want.physical_plan.slots, gangs, b) == cost
@@ -265,3 +266,191 @@ def test_plan_problems_names_each_broken_rule():
     bad[0, 1, 0] = 1  # job 1 on three GPUs over two nodes
     assert len(reference.plan_problems(bad, np.array([0, 1, 2]), gangs)) == 2
     assert reference.plan_problems(slots, np.array([0, 1]), gangs)  # job 2 not active
+
+
+# --------------------------------------------------------------------------- #
+# a cluster with GPU types and racks
+# --------------------------------------------------------------------------- #
+def typed(nodes=16, per_rack=4):
+    """The cell on ``nodes`` nodes, half A100 and half V100, in racks."""
+    spec = small(CELLS[0], nodes)
+    spec["config"]["cluster"].update(
+        node_gpu_types=[["a100", nodes // 2], ["v100", nodes - nodes // 2]],
+        nodes_per_rack=per_rack)
+    return spec
+
+
+def _valid_plan(rng, kc, kl, gangs):
+    """Every job on ``gang`` GPUs of one node, in one pack slot; new ids.
+    Gangs are powers of two, so that every cost is exact in float64."""
+    slots = np.full((kc, kl, 2), -1, np.int64)
+    for n, p in itertools.product(range(kc), range(2)):
+        u = 0
+        while u < kl:
+            if rng.random() < 0.3:
+                u += 1
+                continue
+            g = int(rng.choice([g for g in (1, 2, 4) if g <= kl - u]))
+            j = len(gangs)
+            gangs[j] = g
+            slots[n, u:u + g, p] = j
+            u += g
+    return slots
+
+
+def _instance(kc, kl, seed, crossing):
+    """A previous plan and a logical one: the previous plan with its nodes
+    and GPUs shuffled and some nodes laid out anew; with ``crossing``, only
+    the first node and the last (of another type) swapped."""
+    rng = np.random.default_rng(seed)
+    gangs = {}
+    prev = _valid_plan(rng, kc, kl, gangs)
+    if crossing:
+        logical = prev[[kc - 1] + list(range(1, kc - 1)) + [0]].copy()
+    else:
+        logical = prev[rng.permutation(kc)][:, rng.permutation(kl)].copy()
+        fresh = rng.choice(kc, size=2, replace=False)
+        logical[fresh] = _valid_plan(rng, 2, kl, gangs)
+    return prev, logical, gangs
+
+
+def _brute_force(prev, logical, gangs, types, racks):
+    """The least relabelling cost over every node permutation and every GPU
+    permutation of each node pair, from the reference's definitions."""
+    kc, kl = prev.shape[:2]
+    keep = set(prev[prev >= 0].tolist()) & set(logical[logical >= 0].tolist())
+
+    def gpu(a, b):
+        return sum(0.5 / gangs[j] for j in ({*a} ^ {*b}) & keep)
+
+    pair = {(i, lo): min(sum(gpu(prev[i, u], logical[lo, v[u]]) for u in range(kl))
+                         for v in itertools.permutations(range(kl)))
+            for i in range(kc) for lo in range(kc)}
+    best = np.inf
+    for host in itertools.permutations(range(kc)):  # host[l]: physical node
+        if any(types[host[lo]] != types[lo] for lo in range(kc)):
+            continue
+        best = min(best, sum(pair[host[lo], lo] + 0.5 * (racks[host[lo]] != racks[lo])
+                             for lo in range(kc)))
+    return best
+
+
+TYPED_INSTANCES = {
+    "5x2-two-types": (5, 2, ["a100"] * 3 + ["v100"] * 2, 2, 21, False),
+    "4x4-two-types": (4, 4, ["a100", "a100", "v100", "v100"], 2, 22, False),
+    "6x2-three-types": (6, 2, ["a100", "a100", "v100", "v100", "tpu-v5e", "tpu-v5e"], 3, 23, False),
+    "4x4-untyped-optimum-crosses": (4, 4, ["a100", "a100", "v100", "v100"], 2, 24, True),
+}
+
+
+@pytest.mark.parametrize("case", TYPED_INSTANCES, ids=list(TYPED_INSTANCES))
+def test_the_typed_racked_reference_is_the_brute_force_optimum(case):
+    kc, kl, types, per_rack, seed, crossing = TYPED_INSTANCES[case]
+    types, racks = np.array(types), np.arange(kc) // per_rack
+    prev, logical, gangs = _instance(kc, kl, seed, crossing)
+    cost, phys, node_map = reference.relabel(prev, logical, gangs, types=types, racks=racks)
+    assert cost == _brute_force(prev, logical, gangs, types, racks)
+    assert reference.plan_cost(prev, phys, gangs, logical, node_map=node_map, racks=racks) == cost
+    active = np.array(list(gangs))
+    assert reference.plan_problems(phys, active, gangs, logical, types=types,
+                                   node_map=node_map) == []
+    if crossing:
+        untyped, _, untyped_map = reference.relabel(prev, logical, gangs, racks=racks)
+        assert untyped < cost
+        assert (types[untyped_map] != types).any()
+
+
+@pytest.mark.parametrize("precision", ["float64", "bfloat16"])
+@pytest.mark.parametrize("case", TYPED_INSTANCES, ids=list(TYPED_INSTANCES))
+def test_no_types_and_no_racks_read_as_one_type_in_one_rack(case, precision):
+    kc, kl, _, _, seed, crossing = TYPED_INSTANCES[case]
+    prev, logical, gangs = _instance(kc, kl, seed, crossing)
+    one_type, one_rack = np.full(kc, "a100"), np.zeros(kc, np.int64)
+    cost, phys, node_map = reference.relabel(prev, logical, gangs, precision)
+    cost1, phys1, node_map1 = reference.relabel(prev, logical, gangs, precision,
+                                                types=one_type, racks=one_rack)
+    assert cost == cost1
+    assert np.array_equal(phys, phys1) and np.array_equal(node_map, node_map1)
+    assert reference.plan_cost(prev, phys, gangs, logical) == reference.plan_cost(
+        prev, phys, gangs, logical, node_map=node_map, racks=one_rack)
+    active = np.array(list(gangs))
+    assert reference.plan_problems(phys, active, gangs, logical) == reference.plan_problems(
+        phys, active, gangs, logical, types=one_type, node_map=node_map)
+
+
+def test_the_program_is_correct_on_a_typed_racked_cluster():
+    res = harness.run(typed(), 2**31 + 7, 0.5, False, time.perf_counter(), FAKE_DEVICE)
+    assert res["correct"] is True, res["checks"]
+    rounds = res["_rounds"]
+    assert rounds and all(r.migration is not None for r in rounds)
+    # the type and rack terms are at work: without them the same rounds fail
+    assert harness.check(rounds, res["_gangs"])["cost_gap"]["value"] > 0
+
+
+def _hosts_swapped_across_types(prev, logical, gangs, res):
+    """Two logical nodes of two GPU types trade their physical nodes."""
+    na = np.array(res.node_assignment, np.int64)
+    types = np.array(prev.cluster.node_types())
+    slots = res.physical_plan.slots
+    a = next(lo for lo in range(na.size) if types[lo] == "a100" and (slots[na[lo]] != -1).any())
+    b = next(lo for lo in range(na.size) if types[lo] == "v100"
+             and not np.array_equal(slots[na[lo]], slots[na[a]]))
+    swapped = slots.copy()
+    swapped[[na[a], na[b]]] = slots[[na[b], na[a]]]
+    na[[a, b]] = na[[b, a]]
+    return _result(prev, swapped, res.matching_cost, na)
+
+
+def _rack_term_dropped(prev, logical, gangs, res):
+    cl = prev.cluster
+    racks = np.array([cl.rack_of(k) for k in range(cl.num_nodes)])
+    na = np.asarray(res.node_assignment)
+    res.matching_cost -= 0.5 * int((racks[na] != racks).sum())
+    return res
+
+
+@pytest.mark.parametrize(
+    "fault,number", [(_hosts_swapped_across_types, "invalid_plans"),
+                     (_rack_term_dropped, "cost_gap")],
+    ids=["plan-relabelled-across-types", "cost-without-rack-term"],
+)
+def test_a_fault_on_a_typed_racked_cluster_is_not_correct(monkeypatch, fault, number):
+    _fused_plan_patched(monkeypatch, fault)
+    res, _ = _run(typed())
+    assert res["correct"] is False
+    assert res["checks"][number]["value"] > 0
+
+
+@pytest.mark.parametrize("config", ["shockwave-512x4", "helios-saturn-262x8"])
+def test_a_cluster_without_types_or_racks_builds_the_plain_spec(config):
+    from repro.core.cluster import ClusterSpec
+
+    cl = harness.load_json(os.path.join(ROOT, "bench", "configs", config + ".json"))["cluster"]
+    assert harness.cluster_spec(cl) == ClusterSpec(cl["num_nodes"], cl["gpus_per_node"])
+    assert harness.layout(cl) == (None, None)
+
+
+def test_a_typed_racked_cluster_builds_its_spec():
+    from repro.core.cluster import ClusterSpec
+
+    cl = typed(8, 3)["config"]["cluster"]
+    want = ClusterSpec(8, 4, node_gpu_types=("a100",) * 4 + ("v100",) * 4, nodes_per_rack=3)
+    assert harness.cluster_spec(cl) == want
+    types, racks = harness.layout(cl)
+    assert types.tolist() == list(want.node_types())
+    assert racks.tolist() == [want.rack_of(k) for k in range(8)]
+
+
+@pytest.mark.parametrize("extra", [
+    {"gpu_type": "v100"},
+    {"node_gpu_types": [["a100", 8], ["v100", 7]]},
+    {"node_gpu_types": [["a100", 8], ["h100", 8]]},
+    {"node_gpu_types": [["a100", 16.0]]},
+    {"nodes_per_rack": -1},
+    {"nodes_per_rack": "4"},
+], ids=["unknown-key", "counts-short", "unknown-type", "count-not-whole", "racks-negative",
+        "racks-not-a-number"])
+def test_a_cluster_key_the_harness_cannot_honour_raises(extra):
+    cl = dict(num_nodes=16, gpus_per_node=4, **extra)
+    with pytest.raises(ValueError):
+        harness.cluster_spec(cl)
