@@ -62,11 +62,12 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.cluster import EMPTY, PlacementPlan, count_migrations
-from repro.core.matching.auction import _inverse_assignment, _make_bid_round, _top2
+from repro.core.matching.auction import _NEG, _inverse_assignment, _make_bid_round, _top2
 from repro.core.migration import (
     MigrationResult,
     _cost_scale,
     _relabel_penalties,
+    node_type_ids,
     plan_migration,
 )
 from repro.obs.tracer import tracer_of
@@ -124,18 +125,44 @@ def _pair_top2(use_kernel: bool, tb: float):
     return lambda cost, p: _top2((tb * _ramp(*cost.shape, cost.dtype) - cost) - p[None, :])
 
 
-def _pair_auction(cost, eps_min, init_prices, init_col_of, warm, max_iters, use_kernel, tb):
+def _feasible_top2(feasible, tb: float):
+    """Bid top-2 over the ``feasible`` entries of a raw COST matrix alone:
+    the others never win a bid and never set its increment.  A row with a
+    single feasible entry (a GPU type of one node) has no second value and
+    bids ``eps`` above the price, as against an equal second."""
+
+    def top2(cost, p):
+        vals = (tb * _ramp(*cost.shape, cost.dtype) - cost) - p[None, :]
+        best_v, best_j, second_v = _top2(jnp.where(feasible, vals, _NEG))
+        return best_v, best_j, jnp.where(second_v > _NEG / 2, second_v, best_v)
+
+    return top2
+
+
+def _pair_auction(
+    cost, eps_min, init_prices, init_col_of, warm, max_iters, use_kernel, tb, feasible=None
+):
     """One square Jacobi auction with explicit initial state, on a raw
     scaled COST matrix (benefit assembled in the bid's top-2 — see
     :func:`_pair_top2`).  The :func:`auction._auction_lap_jit` loop with
     an ``init_col_of``: a warm instance whose initial assignment is
     already complete terminates with ZERO bid rounds (the clean-pair
-    fast path).  Returns ``(col_of, prices, iters, converged)``."""
+    fast path).  ``feasible`` (bool, the cost's shape) restricts the
+    assignment to its true entries, and the span that starts the epsilon
+    schedule to their costs (jnp top-2 only: ``use_kernel`` is not read);
+    a feasible perfect matching must exist.
+    Returns ``(col_of, prices, iters, converged)``."""
     n = cost.shape[-1]
     eps_min = jnp.asarray(eps_min, jnp.float32)
-    span = jnp.maximum(jnp.max(jnp.abs(cost)), 1.0)
+    if feasible is None:
+        span = jnp.max(jnp.abs(cost))
+        top2 = _pair_top2(use_kernel, tb)
+    else:
+        span = jnp.max(jnp.where(feasible, jnp.abs(cost), 0.0))
+        top2 = _feasible_top2(feasible, tb)
+    span = jnp.maximum(span, 1.0)
     eps0 = jnp.where(warm, eps_min, jnp.maximum(span / 4.0, eps_min))
-    bid_round = _make_bid_round(cost, n, _pair_top2(use_kernel, tb), dense=True)
+    bid_round = _make_bid_round(cost, n, top2, dense=True)
 
     def cond(state):
         _, col_of, eps, it = state
@@ -180,6 +207,7 @@ def _fused_round(
     cache_prices,    # (kc*kc, kl) f32 — last round's pair prices
     cache_node_prices,  # (kc,) f32
     cache_valid,     # () bool
+    node_type=None,  # (kc,) int32 GPU type ids; None = one type
     *,
     kc: int,
     kl: int,
@@ -194,7 +222,20 @@ def _fused_round(
     host needs comes back in the single returned tuple (one readout).
     Each step runs under a ``jax.named_scope`` of its name (``diff``,
     ``assemble``, ``pair_auction``, ``node_match``, ``scatter``), which
-    the compiled program keeps in every operation's ``op_name``."""
+    the compiled program keeps in every operation's ``op_name``.
+
+    With ``node_type`` the node match is type-feasible: a logical node is
+    matched only to physical nodes of its own type, so the type rule is
+    never a price.  As a price (a block of ``2 kl kc + 1`` units, x16
+    scaled at 512x4) it started the epsilon schedule at a quarter of the
+    block, every node price climbed past 2^15, where f32's spacing (2^-8)
+    is over twice the last phase's ``eps`` (1/513): a bid of ``eps`` no
+    longer raised a price and the match never converged.  Masked, prices
+    stay within the span of the feasible costs (pair totals and rack
+    halves), far below ``2^24 * eps``.  The cached prices are shifted to a
+    zero minimum within each type: no bid crosses types, so the blocks'
+    prices could otherwise drift apart round over round.  Without
+    ``node_type`` (``None``, no leaf) the program is the untyped one."""
     n_pairs = kc * kc
     eps_pair = (tb_pair if tb_pair > 0.0 else 1.0) / (kl + 1)
     eps_node = (tb_node if tb_node > 0.0 else 1.0) / (kc + 1)
@@ -268,15 +309,24 @@ def _fused_round(
         picked = jnp.take_along_axis(cost_p, col_of[:, :, None], axis=2)
         total_scaled = picked[:, :, 0].sum(axis=1)  # (n_pairs,)
         node_cost = total_scaled.reshape(kc, kc) + pen_scaled
+        if node_type is None:
+            feasible = None
+            price_floor = cache_node_prices.min()
+        else:
+            feasible = node_type[:, None] == node_type[None, :]
+            price_floor = jnp.min(
+                jnp.where(feasible, cache_node_prices[None, :], jnp.inf), axis=1
+            )
         node_col, node_prices, node_iters, node_conv = _pair_auction(
             node_cost,
             eps_node,
-            jnp.where(cache_valid, cache_node_prices - cache_node_prices.min(), 0.0),
+            jnp.where(cache_valid, cache_node_prices - price_floor, 0.0),
             jnp.full((kc,), -1, jnp.int32),
             False,
             max_iters,
             False,  # node instance: plain jnp assembly (one LAP, no fan-out win)
             tb_node,
+            feasible,
         )
         matching_cost_scaled = jnp.sum(
             jnp.take_along_axis(node_cost, jnp.maximum(node_col, 0)[:, None], axis=1)[:, 0]
@@ -295,9 +345,12 @@ def _fused_round(
     converged = jnp.all(conv) & node_conv
     # pair bid rounds summed, node match's, dirty pairs, and the vmapped pair
     # loop's trip count (it runs until its slowest pair converges)
-    stats = jnp.stack(
-        [iters.sum(), node_iters, dirty.sum().astype(jnp.int32), iters.max()]
-    )
+    counts = [iters.sum(), node_iters, dirty.sum().astype(jnp.int32), iters.max()]
+    if node_type is not None:
+        # the top node price in eps units: below 2^24 a bid of eps moves it
+        top = jnp.minimum(jnp.max(node_prices) / eps_node, float(2**31 - 128))
+        counts.append(top.astype(jnp.int32))
+    stats = jnp.stack(counts)
     return (
         phys,
         node_assignment,
@@ -322,6 +375,7 @@ def lower_fused_round(
     use_kernel: bool = False,
     tie_break: bool = False,
     max_iters: int = 20_000,
+    typed: bool = False,
     sharding=None,
 ):
     """Lower :func:`_fused_round` for one cluster shape without running it.
@@ -329,7 +383,8 @@ def lower_fused_round(
     The arguments are shapes only (``n_weights`` is the weight table's
     length, ``max_id + 2``), so ``.compile()`` on the result gives the
     program a :class:`FusedMigrationPlanner` of that shape would run, and
-    its ``as_text()`` / ``memory_analysis()``.  ``sharding`` places every
+    its ``as_text()`` / ``memory_analysis()``; ``typed`` gives the program
+    of a cluster of several GPU types.  ``sharding`` places every
     argument, e.g. on a described device of a chip that is not attached.
     """
 
@@ -349,6 +404,7 @@ def lower_fused_round(
         arg((kc * kc, kl), jnp.float32),
         arg((kc,), jnp.float32),
         arg((), jnp.bool_),
+        arg((kc,), jnp.int32) if typed else None,
         kc=kc,
         kl=kl,
         shards=shards,
@@ -411,6 +467,10 @@ class FusedMigrationPlanner:
             # rounds of the vmapped pair loop: its slowest pair's bid rounds
             "fused_pair_trips": 0,
             "fused_readouts": 0,
+            # rounds whose node match ran type-feasible and converged, and
+            # each typed round's top node price in eps units (fallbacks too)
+            "fused_typed_rounds": 0,
+            "fused_node_price_top": 0,
         }
 
     def invalidate(self) -> None:
@@ -488,15 +548,21 @@ class FusedMigrationPlanner:
         with tracer.span("migrate.fused.prepare"):
             # Health terms enter the fused program EXACTLY as the host
             # planner computes them: the same _relabel_penalties matrix
-            # (down-node domination, straggler-drain half-units, type/rack
+            # (down-node domination, straggler-drain half-units, rack
             # terms) is scaled and added to the in-program node cost, and
             # its magnitude counts against the same f32 mantissa budget
             # below — so fused plans with health terms on stay
-            # bit-identical to the host path.
+            # bit-identical to the host path.  Its GPU-type block is the
+            # exception: the node match leaves pairs of two types out
+            # (node_type) instead of pricing them, so only entries of one
+            # type enter the node cost and the budget.
             occupied_logical = (new_logical.slots != EMPTY).any(axis=(1, 2))
             pen = _relabel_penalties(
                 cluster, down_nodes, occupied_logical, speed_factor
             )
+            node_type = node_type_ids(cluster)
+            if pen is not None and node_type is not None:
+                pen = np.where(node_type[:, None] == node_type[None, :], pen, 0.0)
             pen_max = 0.0 if pen is None else float(pen.max())
 
             # f32 exactness budget: the largest scaled node-cost magnitude
@@ -547,6 +613,7 @@ class FusedMigrationPlanner:
                     jnp.asarray(weights),
                     jnp.asarray(pen_scaled),
                 )
+                type_ids = None if node_type is None else jnp.asarray(node_type)
 
         if not in_budget:
             self.stats["fused_host_fallbacks"] += 1
@@ -563,6 +630,7 @@ class FusedMigrationPlanner:
             out = _fused_round(
                 *inputs,
                 *cache,
+                type_ids,
                 kc=kc,
                 kl=kl,
                 shards=self.shards,
@@ -583,6 +651,12 @@ class FusedMigrationPlanner:
                 (phys_dev, node_assign_dev, cost_dev, conv_dev, stats_dev)
             )
         self.stats["fused_readouts"] += 1
+        # the program's counters, kept on a fallback round too
+        self.stats["fused_bid_iters"] += int(stats[0]) + int(stats[1])
+        self.stats["fused_node_iters"] += int(stats[1])
+        self.stats["fused_pair_trips"] += int(stats[3])
+        if node_type is not None:
+            self.stats["fused_node_price_top"] += int(stats[4])
 
         if not bool(converged):
             self.stats["fused_host_fallbacks"] += 1
@@ -603,9 +677,8 @@ class FusedMigrationPlanner:
             self.stats["fused_rounds"] += 1
             self.stats["fused_pair_instances"] += kc * kc
             self.stats["fused_dirty_pairs"] += int(stats[2])
-            self.stats["fused_bid_iters"] += int(stats[0]) + int(stats[1])
-            self.stats["fused_node_iters"] += int(stats[1])
-            self.stats["fused_pair_trips"] += int(stats[3])
+            if node_type is not None:
+                self.stats["fused_typed_rounds"] += 1
 
             phys_plan = PlacementPlan(cluster, np.asarray(phys, np.int64))
             n_mig = count_migrations(prev, phys_plan)

@@ -96,6 +96,18 @@ CROSS_RACK_COST = 0.5
 STRAGGLER_DRAIN_COST = 1.0
 
 
+def node_type_ids(cluster) -> Optional[np.ndarray]:
+    """Each node's GPU type as an int32 id, or ``None`` on a cluster of one
+    type.  The relabelling is type-preserving: physical node ``k`` may host
+    logical node ``l`` only where ``ids[k] == ids[l]``.  The host planner
+    prices every other pair out (:func:`_relabel_penalties`); the fused
+    program leaves them out of the node match."""
+    if not cluster.is_heterogeneous:
+        return None
+    _, ids = np.unique(np.array(cluster.node_types()), return_inverse=True)
+    return ids.astype(np.int32)
+
+
 def _relabel_penalties(
     cluster,
     down_nodes: Optional[np.ndarray] = None,
@@ -132,7 +144,7 @@ def _relabel_penalties(
     Returns ``None`` for healthy homogeneous single-rack clusters — the
     seed path, where the node cost matrix is untouched (bit-for-bit).
     """
-    hetero = cluster.is_heterogeneous
+    types = node_type_ids(cluster)
     racked = cluster.has_topology
     downs = (
         np.asarray([], dtype=np.int64)
@@ -144,13 +156,12 @@ def _relabel_penalties(
         sf = np.asarray(speed_factor, dtype=np.float64)
         if (sf != 1.0).any():
             slow = sf
-    if not hetero and not racked and len(downs) == 0 and slow is None:
+    if types is None and not racked and len(downs) == 0 and slow is None:
         return None
     kc = cluster.num_nodes
     pen = np.zeros((kc, kc), dtype=np.float64)
     base = 2.0 * cluster.gpus_per_node * kc + 1.0
-    if hetero:
-        types = np.array(cluster.node_types())
+    if types is not None:
         pen += base * (types[:, None] != types[None, :])
     if racked:
         racks = np.array([cluster.rack_of(i) for i in range(kc)])

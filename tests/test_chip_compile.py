@@ -106,3 +106,17 @@ def test_fused_round_compiles_at_2048_gpus(one_chip, monkeypatch, use_kernel):
     ops = index_ops_by_scope(compiled.as_text())
     assert "pair_auction" not in ops, ops
     assert ops.get("node_match") == 2, ops
+
+
+@pytest.mark.usefixtures("no_compile_cache")
+def test_typed_fused_round_compiles_at_2048_gpus(one_chip):
+    """The program of a cluster of several GPU types (256 A100 + 256 V100
+    nodes): the type-feasible node match adds no kernel and no gather or
+    scatter to the bid loop."""
+    compiled = lower_fused_round(
+        KC, KL, PMAX, N_WEIGHTS, typed=True, sharding=one_chip
+    ).compile()
+    _check(compiled, False)
+    ops = index_ops_by_scope(compiled.as_text())
+    assert "pair_auction" not in ops, ops
+    assert ops.get("node_match") == 2, ops

@@ -20,6 +20,7 @@ churn-replay differential gate:
   pair axis preserves the full physical relabelling.
 """
 
+import itertools
 import re
 
 import numpy as np
@@ -28,7 +29,7 @@ from hypothesis import given, settings, strategies as st
 
 import jax
 
-from repro.core.cluster import ClusterSpec
+from repro.core.cluster import EMPTY, ClusterSpec, PlacementPlan
 from repro.core.fused import FusedMigrationPlanner
 from repro.core.migration import plan_migration
 from repro.core.placement import place_without_packing
@@ -401,3 +402,236 @@ def test_pair_auction_compiles_without_gather_or_scatter():
     assert "pair_auction" not in ops, ops
     assert ops.get("node_match", 0) > 0, ops
     assert ops.get("scatter", 0) > 0, ops
+
+
+# --------------------------------------------------------------------------- #
+# clusters of several GPU types: the type-feasible node match
+# --------------------------------------------------------------------------- #
+def _saturated_node_totals(rng, kc, keep_diagonal=False):
+    """Node-pair totals (half units) shaped like a saturated round's: moving
+    logical node ``j`` onto physical node ``i`` costs everything on both,
+    ``a[i] + b[j]``, less what a few node pairs share (4 a logical node), so
+    nearly every row ties across its whole type.  ``keep_diagonal`` makes
+    the identity the cheap choice, as in a cluster's first migrated round."""
+    a = rng.integers(0, 9, kc) / 2.0
+    b = rng.integers(0, 9, kc) / 2.0
+    t = a[:, None] + b[None, :]
+    i, j = rng.integers(0, kc, 4 * kc), np.repeat(np.arange(kc), 4)
+    t[i, j] -= 2 * np.minimum(np.minimum(a[i], b[j]), rng.integers(1, 5, 4 * kc) / 2.0)
+    if keep_diagonal:
+        t[np.arange(kc), np.arange(kc)] = 0.0
+    return t
+
+
+def test_512_node_typed_match_converges_where_the_priced_type_block_cannot():
+    """512 nodes x 4 GPUs, 256 A100 and 256 V100 nodes in racks of 16, cost
+    scale 16.  Leaving pairs of two types out of the node match, a second
+    round warm-started from the first one's prices converges to scipy's
+    optimum on each type block.  Pricing the type block instead (4,097
+    units, x16), as the host planner does in float64, starts the epsilon
+    schedule at a quarter of that block: every node price climbs past
+    2^24 eps, where f32 cannot add ``eps`` to a price, and the same
+    instance runs to the 20,000-iteration cap without converging."""
+    import jax.numpy as jnp
+    from scipy.optimize import linear_sum_assignment
+
+    from repro.core.fused import _pair_auction
+
+    kc, kl, scale, cap = 512, 4, 16.0, 20_000
+    eps = 1.0 / (kc + 1)
+    types = np.repeat([0, 1], kc // 2)
+    feasible = types[:, None] == types[None, :]
+    racks = np.arange(kc) // 16
+    rack = 0.5 * (racks[:, None] != racks[None, :])
+    rng = np.random.default_rng(0)
+    costs = [
+        np.where(feasible, (_saturated_node_totals(rng, kc, first) + rack) * scale, 0.0)
+        for first in (True, False)
+    ]
+
+    def solve(cost, prices, mask):
+        col, p, it, conv = _pair_auction(
+            jnp.asarray(cost, jnp.float32), eps, jnp.asarray(prices, jnp.float32),
+            jnp.full((kc,), -1, jnp.int32), False, cap, False, 0.0,
+            None if mask is None else jnp.asarray(mask),
+        )
+        return np.asarray(col), np.asarray(p), int(it), bool(conv)
+
+    _, p1, _, conv1 = solve(costs[0], np.zeros(kc), feasible)
+    assert conv1
+    warm = p1 - np.where(feasible, p1[None, :], np.inf).min(axis=1)
+    col, p2, iters, conv = solve(costs[1], warm, feasible)
+    assert conv and iters < cap
+    assert (types[col] == types).all()
+    blocks = [np.flatnonzero(types == t) for t in (0, 1)]
+    optimum = sum(costs[1][np.ix_(b, b)][linear_sum_assignment(costs[1][np.ix_(b, b)])].sum()
+                  for b in blocks)
+    assert costs[1][np.arange(kc), col].sum() == optimum
+    assert p2.max() / eps < 2**24
+
+    base = 2.0 * kl * kc + 1.0
+    priced = costs[1] + np.where(feasible, 0.0, (base + rack) * scale)
+    _, p_priced, iters, conv = solve(priced, np.zeros(kc), None)
+    assert not conv and iters == cap
+    assert p_priced.max() / eps >= 2**24
+    top = np.float32(p_priced.max())
+    assert top + np.float32(eps) == top  # a bid of eps no longer moves it
+
+
+def _typed_round_plans(rng, kc, kl, gangs, prev=None):
+    """A previous plan and a logical plan of whole-node jobs of 1, 2 or 4
+    GPUs (one pack slot each): the previous plan's nodes and GPUs shuffled,
+    a fifth of its jobs gone and two nodes laid out anew."""
+
+    def fresh(n):
+        slots = np.full((n, kl, 2), EMPTY, np.int64)
+        for node, p in itertools.product(range(n), range(2)):
+            u = 0
+            while u < kl:
+                if rng.random() < 0.3:
+                    u += 1
+                    continue
+                g = int(rng.choice([g for g in (1, 2, 4) if g <= kl - u]))
+                gangs[len(gangs)] = g
+                slots[node, u:u + g, p] = len(gangs) - 1
+                u += g
+        return slots
+
+    prev = fresh(kc) if prev is None else prev
+    logical = prev[rng.permutation(kc)][:, rng.permutation(kl)].copy()
+    gone = [j for j in np.unique(logical[logical != EMPTY]) if rng.random() < 0.2]
+    logical[np.isin(logical, gone)] = EMPTY
+    logical[rng.choice(kc, size=2, replace=False)] = fresh(2)
+    return prev, logical
+
+
+def _typed_cluster(types, per_rack):
+    return ClusterSpec(len(types), 4, node_gpu_types=tuple(types), nodes_per_rack=per_rack)
+
+
+def test_typed_racked_planner_matches_host_and_reference_over_replayed_rounds():
+    """32 nodes, 16 A100 and 16 V100 in racks of 8, ten rounds replayed from
+    seeded random plans, each round's physical plan the next one's previous
+    plan: every round is served by the type-feasible fused program, with the
+    host planner's cost and the plain reference's optimum, a node map that
+    keeps every logical node on its GPU type, and a plan the reference finds
+    valid at that cost."""
+    from bench import reference
+
+    cluster = _typed_cluster(["a100"] * 16 + ["v100"] * 16, 8)
+    types = np.array(cluster.node_types())
+    racks = np.arange(32) // 8
+    rng = np.random.default_rng(17)
+    gangs = {}
+    planner = FusedMigrationPlanner()
+    prev = None
+    for t in range(10):
+        prev, logical = _typed_round_plans(rng, 32, 4, gangs, prev)
+        a, b = PlacementPlan(cluster, prev.copy()), PlacementPlan(cluster, logical.copy())
+        res = planner.plan(a, b, gangs)
+        host = plan_migration(a, b, gangs, algorithm="node", backend="scipy")
+        opt, _, _ = reference.relabel(prev, logical, gangs, types=types, racks=racks)
+        assert res.algorithm == "node-fused", f"round {t}"
+        assert res.matching_cost == host.matching_cost == opt, f"round {t}"
+        node_map = np.asarray(res.node_assignment)
+        assert (types[node_map] == types).all(), f"round {t}"
+        plan = res.physical_plan.slots
+        active = np.unique(logical[logical != EMPTY])
+        assert reference.plan_problems(plan, active, gangs, logical, types=types,
+                                       node_map=node_map) == [], f"round {t}"
+        assert reference.plan_cost(prev, plan, gangs, logical, node_map=node_map,
+                                   racks=racks) == opt, f"round {t}"
+        prev = plan
+    assert planner.stats["fused_host_fallbacks"] == 0
+    assert planner.stats["fused_typed_rounds"] == planner.stats["fused_rounds"] == 10
+
+
+def test_a_gpu_type_of_one_node_keeps_its_node():
+    """Seven A100 nodes and one V100 node: the V100 type block is a single
+    node, with no second-best value to bid against.  The round converges
+    on the device, the V100 logical node stays on the V100 node, and no
+    price runs away."""
+    from bench import reference
+
+    cluster = _typed_cluster(["a100"] * 7 + ["v100"], 4)
+    types = np.array(cluster.node_types())
+    rng = np.random.default_rng(3)
+    gangs = {}
+    prev, logical = _typed_round_plans(rng, 8, 4, gangs)
+    planner = FusedMigrationPlanner()
+    res = planner.plan(PlacementPlan(cluster, prev), PlacementPlan(cluster, logical), gangs)
+    opt, _, _ = reference.relabel(prev, logical, gangs, types=types, racks=np.arange(8) // 4)
+    assert res.algorithm == "node-fused"
+    assert res.node_assignment[7] == 7
+    assert res.matching_cost == opt
+    assert 0 < planner.stats["fused_node_price_top"] < 2**24
+
+
+def test_one_gpu_type_runs_the_untyped_program():
+    """A cluster whose nodes all carry one GPU type has no type ids: the
+    planner runs the program of an untyped cluster, lowered with no type
+    argument, and decides as on the plain cluster; only a typed lowering
+    takes the (kc,) type ids."""
+    from repro.core.fused import lower_fused_round
+    from repro.core.migration import node_type_ids
+
+    uniform = _typed_cluster(["a100"] * 8, 0)
+    plain = ClusterSpec(8, 4)
+    assert node_type_ids(uniform) is None
+    assert node_type_ids(_typed_cluster(["v100"] * 4 + ["a100"] * 4, 0)).tolist() == [1] * 4 + [0] * 4
+    rng = np.random.default_rng(5)
+    gangs = {}
+    prev, logical = _typed_round_plans(rng, 8, 4, gangs)
+    out = []
+    for cluster in (uniform, plain):
+        planner = FusedMigrationPlanner()
+        res = planner.plan(PlacementPlan(cluster, prev.copy()),
+                           PlacementPlan(cluster, logical.copy()), gangs)
+        assert planner.stats["fused_rounds"] == 1
+        assert planner.stats["fused_typed_rounds"] == planner.stats["fused_node_price_top"] == 0
+        out.append((res.physical_plan.slots, res.matching_cost))
+    np.testing.assert_array_equal(out[0][0], out[1][0])
+    assert out[0][1] == out[1][1]
+
+    def signature(**kw):
+        """The lowered program's argument types and its counters' length."""
+        text = lower_fused_round(8, 4, 2, 66, **kw).as_text()
+        main = next(line for line in text.splitlines() if "@main(" in line)
+        counters = re.search(r'tensor<(\d+)xi32> \{jax.result_info = "result\[4\]"', main)
+        return re.findall(r"%arg\d+: (tensor<[^>]*>)", main), int(counters.group(1))
+
+    assert signature() == signature(typed=False)
+    args, counters = signature()
+    assert len(args) == 11 and counters == 4
+    args, counters = signature(typed=True)
+    assert args[11:] == ["tensor<8xi32>"] and counters == 5
+
+
+@pytest.mark.parametrize("typed", [False, True], ids=["untyped", "typed"])
+def test_a_fallback_round_keeps_the_programs_counters(typed):
+    """A round cut off at ``max_iters`` falls back to the host planner, and
+    the program's bid, node-match and pair-trip counters (and, typed, the
+    top node price) are still counted; only a converged typed round counts
+    in ``fused_typed_rounds``."""
+    types = ["a100"] * 8 + (["v100"] * 8 if typed else ["a100"] * 8)
+    cluster = _typed_cluster(types, 4 if typed else 0)
+    rng = np.random.default_rng(11)
+    gangs = {}
+    prev, logical = _typed_round_plans(rng, 16, 4, gangs)
+    a, b = PlacementPlan(cluster, prev), PlacementPlan(cluster, logical)
+
+    cut = FusedMigrationPlanner(max_iters=3)
+    res = cut.plan(a, b, gangs)
+    assert res.algorithm == "node-fused-fallback"
+    assert cut.last_fallback_reason == "fused-nonconverged"
+    s = cut.stats
+    assert s["fused_host_fallbacks"] == 1 and s["fused_rounds"] == 0
+    assert s["fused_node_iters"] > 0 and s["fused_pair_trips"] > 0
+    assert s["fused_bid_iters"] >= s["fused_node_iters"] + s["fused_pair_trips"]
+    assert s["fused_typed_rounds"] == 0
+    assert (s["fused_node_price_top"] > 0) == typed
+
+    full = FusedMigrationPlanner()
+    assert full.plan(a, b, gangs).algorithm == "node-fused"
+    assert full.stats["fused_typed_rounds"] == int(typed)
+    assert (0 < full.stats["fused_node_price_top"] < 2**24) == typed
