@@ -368,6 +368,7 @@ class MatchContext:
             "lru_dropped_cols": 0,   # parked prices dropped on shrink-return
             "host_syncs": 0,         # device->host readouts through this ctx
             "instances_invalidated": 0,  # targeted invalidations (node faults)
+            "pack_class_solves": 0,  # packings solved on job classes, no LAP
         }
 
     def get(self, key: tuple) -> Optional[_CtxEntry]:

@@ -411,7 +411,8 @@ class TesseraeScheduler:
         :attr:`match_context`.
 
         The result is discarded — only the side effect matters: the
-        expected node-pair fan-out, final node match and packing LAPs are
+        expected node-pair fan-out, final node match and (on the engine
+        path, ``packing.pack_jobs``) packing LAPs are
         solved through the context NOW (in a real deployment, during the
         scheduler's idle time between rounds), so when ``decide`` runs for
         real with (mostly) the same inputs it memo-hits or warm-starts and
