@@ -12,7 +12,7 @@ Public API:
 
 from repro.core.cluster import ClusterSpec, PlacementPlan, count_migrations
 from repro.core.jobs import JobSpec, JobState
-from repro.core.migration import plan_migration, plan_migration_batched_auction
+from repro.core.migration import plan_migration
 from repro.core.packing import pack_jobs
 from repro.core.placement import place_without_packing
 from repro.core.profiler import ThroughputProfile, register_model
@@ -26,7 +26,6 @@ __all__ = [
     "JobSpec",
     "JobState",
     "plan_migration",
-    "plan_migration_batched_auction",
     "pack_jobs",
     "place_without_packing",
     "ThroughputProfile",

@@ -5,7 +5,7 @@ packing (Algorithm 4) solves one rectangular max-weight matching, and the
 final node-level match is one more square LAP.  Before this module each
 call site picked its own solver (sequential scipy loops in
 ``migration.py``, ``hungarian.solve_lap`` in ``packing.py``, a bespoke
-auction path in ``plan_migration_batched_auction``).  The engine unifies
+batched-auction path for the node-pair fan-out).  The engine unifies
 them behind a *backend registry*:
 
 ==================  =========================================================
@@ -100,13 +100,17 @@ to a power-of-two bucket so jit signatures are reused across rounds),
 solved, and scattered back next to the memoised results, preserving
 per-instance ``converged`` / ``used_fallback`` flags.
 
-**Device residency**: prices and benefit fingerprints live on device as
-``jnp`` arrays end-to-end — price re-assembly, the rectangular price
-certificate and the save-time price repair are device computations, and
-``np.asarray`` happens only at the final assignment readout
-(``col_of`` / ``converged`` / ``iters``).  Fingerprints are the exact f64
-bit patterns of the benefit cells (two uint32 lanes), so fingerprint
-equality is collision-free: a memo hit can never return a stale result.
+**Residency**: auction prices live on the device as ``jnp`` arrays
+end-to-end — price re-assembly, the rectangular price certificate and the
+save-time price repair are device computations, and ``np.asarray``
+happens only at the final assignment readout (``col_of`` / ``converged``
+/ ``iters``).  Identities and benefit fingerprints live on the host: the
+context lookup (identity matching plus the fingerprint compare) is numpy
+and reads nothing back from the device, so an unchanged round costs no
+device readout and an exact backend with a context never touches the
+device.  Fingerprints are the exact f64 bit patterns of the benefit cells
+(two uint32 lanes), so fingerprint equality is collision-free: a memo hit
+can never return a stale result.
 
 Optimality under warm starts: for square instances the ``S * eps_min``
 bound holds for ANY initial prices (both sides of the comparison telescope
@@ -296,8 +300,11 @@ class BatchedMatchResult:
 class _CtxEntry:
     """Identity-keyed state cached from the previous solve of one family.
 
-    ``fp_bits`` and ``prices`` are DEVICE arrays (jnp); everything needed
-    for host control flow (identities, assignments, flags) stays numpy.
+    ``prices`` is a DEVICE array (jnp); everything the host lookup reads
+    (identities, fingerprints) and everything it returns (assignments,
+    flags) is numpy.  ``fp_bits`` has the real (B, Ne, Me) shape, no
+    padding, and is owned by the entry: :meth:`MatchContext.
+    invalidate_instances` poisons it in place.
     """
 
     instance_ids: np.ndarray    # (B,) int64
@@ -306,16 +313,13 @@ class _CtxEntry:
     transposed: bool
     rect: bool
     real_shape: Tuple[int, int]  # (n, m) before any square embedding
-    fp_bits: "object"           # (B, Ne, Me, 2) uint32 jnp — exact f64 bit pattern
+    fp_bits: np.ndarray         # (B, Ne, Me, 2) uint32 — exact f64 bit pattern
     prices: Optional["object"]  # (B, C) float32 jnp — oriented column prices
     owner: Optional[np.ndarray]  # (B, C) int64 — oriented col -> owning oriented row
     col_solve: np.ndarray       # (B, R) int64 oriented solve-space assignment
     final_col_of: np.ndarray    # (B, N) int64 original-space assignment
     converged: np.ndarray       # (B,) bool
     used_fallback: np.ndarray   # (B,) bool
-    #: bucket-padded int32 device copies of (instance_ids, row_ids, col_ids)
-    #: for the fused prologue; None when the ids don't fit the i32 bands
-    ids_dev: Optional[tuple] = None
 
 
 class MatchContext:
@@ -325,9 +329,9 @@ class MatchContext:
     call site picks a ``context_key`` (e.g. ``"migration_pairs"``,
     ``"packing"``) so different LAP families never collide.  Per family
     the context stores, keyed by the caller-supplied instance/row/column
-    *identities*: exact benefit fingerprints, the final auction **prices**
-    (device-resident), and the final assignment.  See the module docstring
-    for the memo / warm / invalidation semantics.
+    *identities*: exact benefit fingerprints (host), the final auction
+    **prices** (device-resident), and the final assignment.  See the
+    module docstring for the memo / warm / invalidation semantics.
 
     A bounded **departed-identity LRU** rides along: when an instance or
     column identity leaves a family (a job finishes or is demoted, a node
@@ -526,11 +530,9 @@ class MatchContext:
             hit = np.nonzero(np.isin(entry.instance_ids, ids))[0]
             if hit.size == 0:
                 continue
-            idx = jnp.asarray(hit.astype(np.int32))
-            entry.fp_bits = jnp.asarray(entry.fp_bits).at[idx].set(
-                jnp.uint32(0xFFFFFFFF)
-            )
+            entry.fp_bits[hit] = np.uint32(0xFFFFFFFF)
             if entry.prices is not None:
+                idx = jnp.asarray(hit.astype(np.int32))
                 entry.prices = jnp.asarray(entry.prices).at[idx].set(0.0)
             count += int(hit.size)
         id_set = {int(i) for i in ids}
@@ -543,7 +545,7 @@ class MatchContext:
         return count
 
     # -- snapshot / restore (crash-resume) -------------------------------- #
-    STATE_VERSION = "tesserae-matchctx-v1"
+    STATE_VERSION = "tesserae-matchctx-v2"
 
     def state_payload(self) -> Tuple[Dict, Dict[str, np.ndarray]]:
         """The context's full state as ``(json-able meta, arrays)`` — the
@@ -572,7 +574,7 @@ class MatchContext:
             arrays[p + "instance_ids"] = e.instance_ids
             arrays[p + "row_ids"] = e.row_ids
             arrays[p + "col_ids"] = e.col_ids
-            arrays[p + "fp_bits"] = np.asarray(e.fp_bits)
+            arrays[p + "fp_bits"] = e.fp_bits
             if e.prices is not None:
                 arrays[p + "prices"] = np.asarray(e.prices, np.float32)
             if e.owner is not None:
@@ -608,8 +610,8 @@ class MatchContext:
     @classmethod
     def from_payload(cls, meta: Dict, get: Callable[[str], np.ndarray]) -> "MatchContext":
         """Rebuild a context from a :meth:`state_payload` meta dict and an
-        array accessor (``get(name) -> ndarray``).  Device arrays
-        (fingerprints, prices, the fused-prologue id buckets) are
+        array accessor (``get(name) -> ndarray``).  Identities and
+        fingerprints are kept as stored, on the host; prices are
         re-materialised on the current default device."""
         if meta.get("version") != cls.STATE_VERSION:
             raise ValueError(
@@ -622,27 +624,14 @@ class MatchContext:
             p = f"e{i}."
             k = em["key"]
             key = (k[0], k[1], bool(k[2]), k[3], bool(k[4]))
-            inst = get(p + "instance_ids")
-            rids = get(p + "row_ids")
-            cids = get(p + "col_ids")
-            ids_dev = None
-            if _ids_i32_safe(inst, rids, cids):
-                nb = _next_pow2(inst.shape[0])
-                nn = _next_pow2(rids.shape[1])
-                nm = _next_pow2(cids.shape[1])
-                ids_dev = (
-                    jnp.asarray(_bucket_vec_i32(inst, nb)),
-                    jnp.asarray(_bucket_mat_i32(rids, nb, nn)),
-                    jnp.asarray(_bucket_mat_i32(cids, nb, nm)),
-                )
             ctx._entries[key] = _CtxEntry(
-                instance_ids=inst,
-                row_ids=rids,
-                col_ids=cids,
+                instance_ids=get(p + "instance_ids"),
+                row_ids=get(p + "row_ids"),
+                col_ids=get(p + "col_ids"),
                 transposed=bool(em["transposed"]),
                 rect=bool(em["rect"]),
                 real_shape=tuple(em["real_shape"]),
-                fp_bits=jnp.asarray(get(p + "fp_bits")),
+                fp_bits=np.array(get(p + "fp_bits"), np.uint32),
                 prices=(
                     jnp.asarray(get(p + "prices")) if em["has_prices"] else None
                 ),
@@ -651,7 +640,6 @@ class MatchContext:
                 final_col_of=get(p + "final_col_of"),
                 converged=get(p + "converged"),
                 used_fallback=get(p + "used_fallback"),
-                ids_dev=ids_dev,
             )
         for j, lm in enumerate(meta["lru"]):
             fam = tuple(
@@ -758,7 +746,7 @@ def _invert_pos(pos: np.ndarray, k_old: int) -> np.ndarray:
 
 
 # --------------------------------------------------------------------------- #
-# Device-resident fingerprints + price machinery
+# Fingerprints (host) + price machinery (device)
 # --------------------------------------------------------------------------- #
 def _f64_bits(a: np.ndarray) -> np.ndarray:
     """Exact fingerprint of f64 values: the raw bit pattern as two uint32
@@ -769,23 +757,25 @@ def _f64_bits(a: np.ndarray) -> np.ndarray:
     return a.view(np.uint32).reshape(*a.shape, 2)
 
 
-@jax.jit
-def _rows_unchanged_dev(new_bits, old_bits, old_idx, row_pos, col_pos):
-    """Per-row exact change detection on device.
+def _rows_unchanged(new_bits, old_bits, old_idx, row_pos, col_pos):
+    """Per-row exact change detection on the host.
 
-    ``new_bits`` (B, N, M, 2) uint32; ``old_bits`` (B0, N0, M0, 2);
-    ``old_idx`` (B,) instance match (-1 = cold); ``row_pos`` (B, N) /
-    ``col_pos`` (B, M) identity positions in the old instance (-1 = new).
-    A row is unchanged iff it existed last round and every SURVIVING
-    column's cell is bit-identical (new columns don't count against it).
+    ``new_bits`` (B, N, M, 2) uint32 (:func:`_f64_bits`); ``old_bits``
+    (B0, N0, M0, 2); ``old_idx`` (B,) instance match (-1 = cold);
+    ``row_pos`` (B, N) / ``col_pos`` (B, M) identity positions in the old
+    instance (-1 = new).  A row is unchanged iff it existed last round and
+    every SURVIVING column's cell is bit-identical (new columns don't count
+    against it).  Each cell's two lanes compare as one uint64 word.
     """
-    ob = jnp.clip(old_idx, 0, None)
-    rp = jnp.clip(row_pos, 0, None)
-    cp = jnp.clip(col_pos, 0, None)
-    gathered = old_bits[ob[:, None, None], rp[:, :, None], cp[:, None, :]]
-    eq = jnp.all(gathered == new_bits, axis=-1)
-    eq = jnp.where((col_pos >= 0)[:, None, :], eq, True)
-    return (row_pos >= 0) & (old_idx >= 0)[:, None] & jnp.all(eq, axis=-1)
+    new = np.ascontiguousarray(new_bits).view(np.uint64)[..., 0]
+    old = np.ascontiguousarray(old_bits).view(np.uint64)[..., 0]
+    _, n0, m0 = old.shape
+    flat = (
+        np.clip(old_idx, 0, None)[:, None, None] * n0
+        + np.clip(row_pos, 0, None)[:, :, None]
+    ) * m0 + np.clip(col_pos, 0, None)[:, None, :]
+    eq = (old.reshape(-1)[flat] == new) | (col_pos < 0)[:, None, :]
+    return (row_pos >= 0) & (old_idx >= 0)[:, None] & eq.all(axis=-1)
 
 
 def _assigned_cols(col_solve: np.ndarray, c: int) -> np.ndarray:
@@ -874,129 +864,6 @@ def _bucket_size(n_solve: int, b: int) -> int:
     if n_solve in (0, b):
         return n_solve
     return min(_next_pow2(n_solve), b)
-
-
-def _bucketed_bits(bits):
-    """Zero-pad a (B, N, M, 2) fingerprint tensor to power-of-two B/N/M so
-    the change-detection jit signature recurs across churn rounds instead
-    of recompiling per (batch, shape) pair.  Padded cells are never
-    consulted: padded batch entries carry ``old_idx == -1``, padded rows
-    ``row_pos == -1`` and padded columns ``col_pos == -1``."""
-    b, n, m, _ = bits.shape
-    nb, nn, nm = _next_pow2(b), _next_pow2(n), _next_pow2(m)
-    if (nb, nn, nm) == (b, n, m):
-        return bits
-    return jnp.pad(bits, ((0, nb - b), (0, nn - n), (0, nm - m), (0, 0)))
-
-
-# --------------------------------------------------------------------------- #
-# Device-side identity matching (fused prologue)
-# --------------------------------------------------------------------------- #
-# x64 is disabled, so device integers are int32 while host identities are
-# int64 (with synthetic embedding pads below _PAD_ID_BASE = -2^40).  The
-# prologue therefore runs on an order- and identity-preserving int32
-# re-encoding with three disjoint bands:
-#
-#   real ids            (-2^30, 2^31)            pass through unchanged
-#   embedding pads      (-2^30 - 2^20, -2^30]    shifted by _I32_PAD_OFFSET
-#   bucket sentinels    below -2^30 - 2^21       power-of-two shape padding
-#
-# Band disjointness means a real id can never collide with a pad or a
-# sentinel after encoding, so device matches are exactly the host matches.
-# Callers whose ids fall outside the real band (or whose embedding exceeds
-# 2^20) keep the host-numpy path (:func:`_positions_in`).
-_I32_PAD_OFFSET = _PAD_ID_BASE + (1 << 30)
-_I32_BUCKET_PAD = -(1 << 30) - (1 << 21)
-
-
-def _ids_i32_safe(*id_arrays: np.ndarray) -> bool:
-    """True when every identity fits its int32 device encoding band: real
-    ids inside (-2^30, 2^31), embedding pads shallow enough (< 2^21 pad
-    rows/cols, i.e. any practical embedding) to stay above the bucket
-    sentinels."""
-    for ids in id_arrays:
-        if ids.size == 0:
-            continue
-        lo, hi = int(ids.min()), int(ids.max())
-        if hi >= (1 << 31):
-            return False
-        if lo <= _PAD_ID_BASE:  # embedding pads present
-            if lo <= _PAD_ID_BASE - (1 << 21) + 1:
-                return False
-            real = ids[ids > _PAD_ID_BASE]
-            if real.size and int(real.min()) <= -(1 << 30):
-                return False
-        elif lo <= -(1 << 30):
-            return False
-    return True
-
-
-def _encode_ids_i32(ids: np.ndarray) -> np.ndarray:
-    return np.where(ids > _PAD_ID_BASE, ids, ids - _I32_PAD_OFFSET).astype(np.int32)
-
-
-def _bucket_vec_i32(ids: np.ndarray, nb: int) -> np.ndarray:
-    """Encode a (B,) instance-id vector into its (nb,) bucket."""
-    out = np.empty(nb, np.int32)
-    out[: ids.shape[0]] = _encode_ids_i32(ids)
-    out[ids.shape[0]:] = (
-        _I32_BUCKET_PAD - np.arange(nb - ids.shape[0], dtype=np.int64)
-    ).astype(np.int32)
-    return out
-
-
-def _bucket_mat_i32(ids: np.ndarray, nb: int, nk: int) -> np.ndarray:
-    """Encode a (B, K) row/col-id matrix into its (nb, nk) bucket.  Padded
-    cells get per-position sentinels: unique within a row (the engine's
-    identity-uniqueness contract extends to the padding) and out of every
-    real/pad band, so they can only ever match OTHER sentinels — and those
-    matches live entirely in the padded region the caller slices off (the
-    fingerprint compare sees bit-equal zero cells there either way)."""
-    b, k = ids.shape
-    out = np.empty((nb, nk), np.int32)
-    out[:b, :k] = _encode_ids_i32(ids)
-    sent = (_I32_BUCKET_PAD - np.arange(nk, dtype=np.int64)).astype(np.int32)
-    out[:b, k:] = sent[k:]
-    out[b:, :] = sent[None, :]
-    return out
-
-
-@jax.jit
-def _positions_in_dev(new_ids, old_ids):
-    """Device counterpart of :func:`_positions_in`: position of each
-    ``new_ids[b, i]`` in ``old_ids[b, :]`` (first occurrence, via stable
-    argsort + left searchsorted — the same tie rule as the host path), or
-    -1 when absent.  int32 ids (see the encoding bands above)."""
-    order = jnp.argsort(old_ids, axis=1, stable=True)
-    sorted_old = jnp.take_along_axis(old_ids, order, axis=1)
-    loc = jax.vmap(lambda so, ni: jnp.searchsorted(so, ni, side="left"))(
-        sorted_old, new_ids
-    )
-    loc = jnp.minimum(loc, old_ids.shape[1] - 1)
-    hit = jnp.take_along_axis(sorted_old, loc, axis=1) == new_ids
-    return jnp.where(hit, jnp.take_along_axis(order, loc, axis=1), -1)
-
-
-@jax.jit
-def _match_prologue_dev(
-    inst, old_inst, rids, old_rids, cids, old_cids, new_bits, old_bits
-):
-    """The fused context-lookup prologue: instance matching, row/column
-    identity matching and the exact fingerprint compare as ONE jitted
-    program with a single 4-tuple readout — replacing the three host-numpy
-    ``_positions_in`` passes plus the separate change-detection sync the
-    host path performs per round."""
-    old_idx = _positions_in_dev(inst[None, :], old_inst[None, :])[0]
-    safe_b = jnp.clip(old_idx, 0, old_inst.shape[0] - 1)
-    matched = old_idx >= 0
-    row_pos = jnp.where(
-        matched[:, None], _positions_in_dev(rids, old_rids[safe_b]), -1
-    )
-    col_pos = jnp.where(
-        matched[:, None], _positions_in_dev(cids, old_cids[safe_b]), -1
-    )
-    unchanged = _rows_unchanged_dev(new_bits, old_bits, old_idx, row_pos, col_pos)
-    return old_idx, row_pos, col_pos, unchanged
 
 
 # --------------------------------------------------------------------------- #
@@ -1361,7 +1228,7 @@ def _solve_lap_batched_impl(
         if rids is None:
             rids = _pad_ids(_as_id_matrix(row_ids, b, n, "row_ids"), ne)
             cids = _pad_ids(_as_id_matrix(col_ids, b, m, "col_ids"), me)
-        bits = jnp.asarray(_f64_bits(benefit_nm))
+        bits = _f64_bits(benefit_nm)
         cand = context.get(key)
         if cand is not None and cand.transposed == transposed and cand.rect == rect:
             entry = cand
@@ -1376,63 +1243,19 @@ def _solve_lap_batched_impl(
     old_idx = row_pos_or = col_pos_or = None
     if entry is not None:
         b0 = entry.instance_ids.shape[0]
-        nb, nn, nm = _next_pow2(b), _next_pow2(ne), _next_pow2(me)
-        # the identity/fingerprint prologue and its readout (a jnp.pad of a
-        # new bucket shape compiles here)
+        # the context lookup: identity matching + the exact fingerprint
+        # compare, all host numpy (no device readout)
         with tracer_of(context.obs).span("lap.prologue"):
-            if entry.ids_dev is not None and _ids_i32_safe(inst, rids, cids):
-                # Device-resident identity matching: instance match, row/col
-                # identity match and the exact fingerprint compare run as ONE
-                # jitted program against the cached device copies of last
-                # round's identities — a single 4-tuple readout instead of
-                # three host-numpy passes plus a separate change-detection
-                # sync.  Bucket-padded inputs keep the jit signature shared
-                # across churn rounds.
-                oi_d, rp_d, cp_d, ru_d = _match_prologue_dev(
-                    jnp.asarray(_bucket_vec_i32(inst, nb)),
-                    entry.ids_dev[0],
-                    jnp.asarray(_bucket_mat_i32(rids, nb, nn)),
-                    entry.ids_dev[1],
-                    jnp.asarray(_bucket_mat_i32(cids, nb, nm)),
-                    entry.ids_dev[2],
-                    _bucketed_bits(bits),
-                    entry.fp_bits,
-                )
-                oi_h, rp_h, cp_h, ru_h = jax.device_get((oi_d, rp_d, cp_d, ru_d))  # tessalint: sync-ok(the match prologue's single documented readout; counted in stats[host_syncs])
-                context.stats["host_syncs"] += 1
-                old_idx = np.asarray(oi_h, np.int64)[:b]
-                row_pos = np.asarray(rp_h, np.int64)[:b, :ne]
-                col_pos = np.asarray(cp_h, np.int64)[:b, :me]
-                row_unchanged = np.asarray(ru_h)[:b, :ne]
-                matched = old_idx >= 0
-            else:
-                # host fallback: ids outside the int32 encoding bands
-                old_idx = _positions_in(inst[None, :], entry.instance_ids[None, :])[0]
-                safe_h = np.clip(old_idx, 0, b0 - 1)
-                row_pos = _positions_in(rids, entry.row_ids[safe_h])
-                col_pos = _positions_in(cids, entry.col_ids[safe_h])
-                matched = old_idx >= 0
-                row_pos[~matched] = -1
-                col_pos[~matched] = -1
-                # bucket-pad the compare inputs (stored fingerprints are padded
-                # at store time) so the jit signature recurs across churn rounds
-                oi_p = np.full(nb, -1, np.int64)
-                oi_p[:b] = old_idx
-                rp_p = np.full((nb, nn), -1, np.int64)
-                rp_p[:b, :ne] = row_pos
-                cp_p = np.full((nb, nm), -1, np.int64)
-                cp_p[:b, :me] = col_pos
-                row_unchanged = np.asarray(  # tessalint: sync-ok(host-fallback path for ids outside the int32 bands; one readout of the row-unchanged verdict)
-                    _rows_unchanged_dev(
-                        _bucketed_bits(bits),
-                        entry.fp_bits,
-                        jnp.asarray(oi_p),
-                        jnp.asarray(rp_p),
-                        jnp.asarray(cp_p),
-                    )
-                )[:b, :ne]
-                context.stats["host_syncs"] += 1
-        safe_b = np.clip(old_idx, 0, b0 - 1)
+            old_idx = _positions_in(inst[None, :], entry.instance_ids[None, :])[0]
+            matched = old_idx >= 0
+            safe_b = np.clip(old_idx, 0, b0 - 1)
+            row_pos = _positions_in(rids, entry.row_ids[safe_b])
+            col_pos = _positions_in(cids, entry.col_ids[safe_b])
+            row_pos[~matched] = -1
+            col_pos[~matched] = -1
+            row_unchanged = _rows_unchanged(
+                bits, entry.fp_bits, old_idx, row_pos, col_pos
+            )
         ne0, me0 = entry.row_ids.shape[1], entry.col_ids.shape[1]
         rows_bij = matched & (ne == ne0) & (row_pos >= 0).all(axis=1)
         cols_bij = matched & (me == me0) & (col_pos >= 0).all(axis=1)
@@ -1691,14 +1514,6 @@ def _solve_lap_batched_impl(
         owner = np.full((b, c), -1, np.int64)
         bb, rr = np.nonzero(col_solve_full >= 0)
         owner[bb, col_solve_full[bb, rr]] = rr
-        ids_dev = None
-        if _ids_i32_safe(inst, rids, cids):
-            nb, nn, nm = _next_pow2(b), _next_pow2(ne), _next_pow2(me)
-            ids_dev = (
-                jnp.asarray(_bucket_vec_i32(inst, nb)),
-                jnp.asarray(_bucket_mat_i32(rids, nb, nn)),
-                jnp.asarray(_bucket_mat_i32(cids, nb, nm)),
-            )
         context.store(
             key,
             _CtxEntry(
@@ -1708,14 +1523,13 @@ def _solve_lap_batched_impl(
                 transposed=transposed,
                 rect=rect,
                 real_shape=(n, m),
-                fp_bits=_bucketed_bits(bits),
+                fp_bits=bits,
                 prices=prices_full,
                 owner=owner,
                 col_solve=col_solve_full,
                 final_col_of=col_of.copy(),
                 converged=converged.copy(),
                 used_fallback=used_fallback.copy(),
-                ids_dev=ids_dev,
             ),
         )
 

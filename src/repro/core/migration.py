@@ -409,27 +409,3 @@ def plan_migration(
         algorithm,
     )
 
-
-def plan_migration_batched_auction(
-    prev: PlacementPlan,
-    new_logical: PlacementPlan,
-    num_gpus_of: Dict[int, int],
-    use_kernel: bool = False,
-) -> MigrationResult:
-    """Beyond-paper: Algorithm 2 with the k_c^2 node-pair LAPs solved as ONE
-    batched JAX auction instead of k_c^2 sequential Hungarian calls.
-
-    Now a thin wrapper over :func:`plan_migration` with the engine's
-    ``auction`` backend (``auction_kernel`` routes the bid top-2 through
-    the Pallas kernel).  Exactness: costs are multiples of
-    ``1/(2*num_gpus)`` and are scaled to integers before solving, so the
-    auction's final epsilon guarantees optimality per instance.
-    """
-    res = plan_migration(
-        prev,
-        new_logical,
-        num_gpus_of,
-        algorithm="node",
-        backend="auction_kernel" if use_kernel else "auction",
-    )
-    return dataclasses.replace(res, algorithm="node-auction")

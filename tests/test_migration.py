@@ -9,7 +9,6 @@ from repro.core.migration import (
     node_level_matching,
     pairwise_migration_cost,
     plan_migration,
-    plan_migration_batched_auction,
     _weight_lookup,
 )
 
@@ -209,7 +208,7 @@ class TestBatchedAuctionMigration:
         rng = np.random.default_rng(seed)
         cluster, p_i, p_j, num_gpus_of = _random_plans(rng, num_nodes=3, gpn=2, n_jobs=6)
         hung = plan_migration(p_i, p_j, num_gpus_of, algorithm="node")
-        auct = plan_migration_batched_auction(p_i, p_j, num_gpus_of)
+        auct = plan_migration(p_i, p_j, num_gpus_of, algorithm="node", backend="auction")
         # optimality of the batched auction == Hungarian on the SAME cost
         assert np.isclose(auct.matching_cost, hung.matching_cost)
         # count may differ from no-remap by partial multi-GPU moves (see

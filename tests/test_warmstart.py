@@ -18,7 +18,7 @@ from repro.core.matching import MatchContext, solve_lap_batched
 from repro.core.matching.engine import (
     _f64_bits,
     _rect_bound_violation,
-    _rows_unchanged_dev,
+    _rows_unchanged,
 )
 
 scipy_lsa = pytest.importorskip("scipy.optimize").linear_sum_assignment
@@ -322,20 +322,16 @@ class TestReplayedTrace:
 
 class TestFingerprints:
     """The context's fingerprints are the exact f64 bit patterns of the
-    benefit cells (device-resident uint32 lanes) — comparison is
-    collision-free, so a memo hit can never return a stale result."""
+    benefit cells (host uint32 lanes) — comparison is collision-free, so
+    a memo hit can never return a stale result."""
 
     def _unchanged(self, new, old, old_idx, row_pos, col_pos):
-        import jax.numpy as jnp
-
-        return np.asarray(
-            _rows_unchanged_dev(
-                jnp.asarray(_f64_bits(new)),
-                jnp.asarray(_f64_bits(old)),
-                jnp.asarray(old_idx),
-                jnp.asarray(row_pos),
-                jnp.asarray(col_pos),
-            )
+        return _rows_unchanged(
+            _f64_bits(new),
+            _f64_bits(old),
+            np.asarray(old_idx),
+            np.asarray(row_pos),
+            np.asarray(col_pos),
         )
 
     def test_bits_roundtrip_exact(self):
@@ -964,11 +960,10 @@ class TestTieBreakIdentityKeyed:
         np.testing.assert_array_equal(pert2, pert)
 
 
-class TestDeviceProloguePath:
-    """ISSUE-6 tentpole: the context lookup (instance/row/col identity
-    matching + fingerprint compare) runs as one fused device program with
-    a single readout.  Pins host/device agreement and the host fallback
-    for ids outside the int32 encoding bands."""
+class TestContextLookup:
+    """The context lookup (instance/row/col identity matching + the exact
+    fingerprint compare) is one host computation: any int64 ids, no
+    device readout of its own."""
 
     def _churn_replay(self, ids_offset=0):
         rng = np.random.default_rng(31)
@@ -986,21 +981,21 @@ class TestDeviceProloguePath:
             costs, _ = _mutate(rng, costs, 1)
         return ctx, plans
 
-    def test_device_and_host_prologue_produce_identical_plans(self):
-        """Ids inside the i32 band take the device prologue; ids beyond
-        2^31 force the host-numpy fallback.  Same costs, same plans."""
-        ctx_dev, plans_dev = self._churn_replay(0)
-        ctx_host, plans_host = self._churn_replay(1 << 32)
-        for a, b in zip(plans_dev, plans_host):
+    def test_plans_do_not_depend_on_id_magnitude(self):
+        """Ids from 0 and ids offset by 2^32 take the same lookup: same
+        costs, same plans."""
+        ctx_lo, plans_lo = self._churn_replay(0)
+        ctx_hi, plans_hi = self._churn_replay(1 << 32)
+        for a, b in zip(plans_lo, plans_hi):
             np.testing.assert_array_equal(a, b)
-        # both replays counted their readouts
-        assert ctx_dev.stats["host_syncs"] > 0
-        assert ctx_host.stats["host_syncs"] > 0
+        # both replays counted their auction readouts
+        assert ctx_lo.stats["host_syncs"] > 0
+        assert ctx_lo.stats["host_syncs"] == ctx_hi.stats["host_syncs"]
 
-    def test_steady_state_rounds_are_single_readout(self):
-        """An unchanged round through the fused prologue costs exactly ONE
-        device->host sync (the prologue readout): the full-memo fast path
-        returns without touching the solver."""
+    def test_steady_state_rounds_read_nothing_back(self):
+        """An unchanged auction round is served by the full-memo fast path
+        from the host lookup alone: no bid iterations, all warm, and no
+        device->host sync."""
         rng = np.random.default_rng(8)
         ctx = MatchContext()
         costs = rng.integers(0, 20, (4, 6, 6)).astype(float)
@@ -1011,4 +1006,28 @@ class TestDeviceProloguePath:
         before = ctx.stats["host_syncs"]
         res = solve_lap_batched(costs, **kw)
         assert res.bid_iters.sum() == 0 and res.warm.all()
-        assert ctx.stats["host_syncs"] - before == 1
+        assert ctx.stats["host_syncs"] - before == 0
+
+    def test_exact_backend_context_stays_on_host(self):
+        """Three churn rounds of an exact backend with a context: the
+        stored fingerprints are a numpy array of the real shape, nothing
+        is read back from the device, and every result equals a
+        context-free solve."""
+        rng = np.random.default_rng(17)
+        ctx = MatchContext()
+        costs = rng.integers(0, 30, (5, 6, 6)).astype(float)
+        ids = np.array([9, 2, 7, 4, 11])
+        for _ in range(3):
+            res = solve_lap_batched(
+                costs, backend="scipy", context=ctx, context_key="exact",
+                instance_ids=ids,
+            )
+            ref = solve_lap_batched(costs, backend="scipy")
+            np.testing.assert_array_equal(res.col_of, ref.col_of)
+            np.testing.assert_array_equal(res.total_cost, ref.total_cost)
+            (entry,) = ctx._entries.values()
+            assert isinstance(entry.fp_bits, np.ndarray)
+            assert entry.fp_bits.shape == (5, 6, 6, 2)
+            costs, _ = _mutate(rng, costs, 2)
+        assert ctx.stats["host_syncs"] == 0
+        assert ctx.stats["memo_instances"] > 0

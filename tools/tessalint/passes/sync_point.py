@@ -2,9 +2,10 @@
 
 The fused decide() path guarantees ONE device→host readout per round
 (``BENCH_fused_decide.json``); the identity-keyed engine guarantees
-readouts only at documented points (assignment extraction, the batched
-match prologue, the LRU park).  Any other transfer is a silent sync that
-shows up as a per-round latency cliff long before a benchmark catches it.
+readouts only at documented points (assignment extraction, the
+rectangular certificate verdict, the LRU park).  Any other transfer is a
+silent sync that shows up as a per-round latency cliff long before a
+benchmark catches it.
 
 In modules the manifest declares device-resident, flag:
 
